@@ -70,10 +70,7 @@ from benchmarks.lane import (  # noqa: E402
 from repro.core.chebyshev import shifts_for_operator  # noqa: E402
 from repro.linalg import Stencil2D5  # noqa: E402
 from repro.parallel import get_backend  # noqa: E402
-from repro.parallel.distributed import (  # noqa: E402
-    make_solver_mesh,
-    shard_map_compat,
-)
+from repro.parallel.distributed import make_solver_mesh  # noqa: E402
 from repro.parallel.reduction import (  # noqa: E402
     hop_payload_bytes,
     reduction_wire_bytes,
@@ -105,12 +102,12 @@ def measured_collective_times(n_dev: int, l: int) -> dict:
     k = 2 * l + 1
     x = jnp.asarray(np.arange(n_dev * k, dtype=np.float64))
     ring = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-    hop = jax.jit(shard_map_compat(
+    hop = jax.jit(jax.shard_map(
         lambda v: lax.ppermute(v, "shards", ring), mesh=mesh,
-        in_specs=P("shards"), out_specs=P("shards")))
-    allred = jax.jit(shard_map_compat(
+        in_specs=P("shards"), out_specs=P("shards"), check_vma=False))
+    allred = jax.jit(jax.shard_map(
         lambda v: lax.psum(v, "shards"), mesh=mesh,
-        in_specs=P("shards"), out_specs=P()))
+        in_specs=P("shards"), out_specs=P(), check_vma=False))
     return {
         "measured_hop_time_s": _time_best(lambda: hop(x)),
         "measured_allreduce_time_s": _time_best(lambda: allred(x)),

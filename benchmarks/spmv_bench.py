@@ -51,7 +51,6 @@ from repro.linalg.sparse import sliced_ell_reorder  # noqa: E402
 from repro.parallel.distributed import (  # noqa: E402
     make_solver_mesh,
     partitioned_solver_ops,
-    shard_map_compat,
 )
 
 
@@ -122,9 +121,10 @@ def main():
     mesh = make_solver_mesh(n_dev)
     arrays, build, _perm = partitioned_solver_ops(op, None, n_dev, "shards")
     arr_specs = jax.tree.map(lambda _: P("shards"), arrays)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda xl, loc: build(loc).apply_a(xl), mesh=mesh,
-        in_specs=(P("shards"), arr_specs), out_specs=P("shards"))
+        in_specs=(P("shards"), arr_specs), out_specs=P("shards"),
+        check_vma=False)
     xp = x[jnp.asarray(plan.perm)]
     dist = jax.jit(fn)
     t_dist = time_best(lambda: dist(xp, arrays))

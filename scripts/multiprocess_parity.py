@@ -75,12 +75,8 @@ def _child_jax_setup():
     import jax
 
     # Cross-process CPU collectives need the gloo TCP backend (the
-    # backend constructor also selects it; doing it here too keeps the
-    # setup explicit for jax versions that read the env var instead).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:  # pragma: no cover - very old/new jax
-        pass
+    # backend constructor also selects it).
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.config.update("jax_enable_x64", True)
     return jax
 

@@ -18,10 +18,23 @@ class CGProblem:
     seed: int = 0       # mesh-generator seed (unstructured kinds only)
 
 
+# The chip runs float32.  At 2048^2 even the float32-rounded exact
+# solution has a true relative residual of 1.7e-5, so the float64-era
+# 1e-6 is out of reach.  The tolerance is on the TRUE residual, which
+# solves certify (chip_smoke.py): on a TPU v5e, p(2)-CG with a residual
+# replacement every 1000 updates certifies 2e-4 in 4757 updates (true
+# 1.83e-4), about 10% more than 1e-3 takes (PERF.md §6).
+F32_TOL = 2e-4
+
+
 def config():
-    # 2000x2000 = 4M unknowns, the paper's Fig. 3 problem size
-    return CGProblem(name="laplace2d", kind="stencil2d", nx=2048, ny=2048)
+    # 2000x2000 = 4M unknowns, the paper's Fig. 3 problem size.  maxit
+    # leaves ~1.7x headroom over the updates a certified F32_TOL solve
+    # took on the chip.
+    return CGProblem(name="laplace2d", kind="stencil2d", nx=2048, ny=2048,
+                     tol=F32_TOL, maxit=8000)
 
 
 def smoke_config():
-    return CGProblem(name="laplace2d-smoke", kind="stencil2d", nx=32, ny=24)
+    return CGProblem(name="laplace2d-smoke", kind="stencil2d", nx=32, ny=24,
+                     tol=F32_TOL)

@@ -590,10 +590,14 @@ def build(
             # restart's lucky check) or re-seeds the gap with the
             # measured discrepancy.  A governed solve therefore never
             # sets ``converged`` from the recursion alone.
-            gap_due = ok & ((governor.safety * gap >= rel) | (rel < tol))
-            pat_due = ok & (rel >= tol) & (
-                upd.astype(dtype) - best_upd
-                >= governor.resolved_patience(l))
+            if governor.verify_only:
+                gap_due = ok & (rel < tol)
+                pat_due = jnp.asarray(False)
+            else:
+                gap_due = ok & ((governor.safety * gap >= rel) | (rel < tol))
+                pat_due = ok & (rel >= tol) & (
+                    upd.astype(dtype) - best_upd
+                    >= governor.resolved_patience(l))
             code = jnp.where(
                 gap_due, jnp.asarray(M.ACTION_GAP_REPLACE, dtype),
                 jnp.where(pat_due,
@@ -670,8 +674,9 @@ def build(
                 jnp.where(fruitful, jnp.zeros((), dtype),
                           gov[M.FRUITLESS] + 1),
                 gov[M.FRUITLESS])
-            stag = jnp.where(fruitless >= governor.demote_after,
-                             jnp.ones((), dtype), gov[M.STAGNATED])
+            stag = gov[M.STAGNATED] if governor.verify_only else jnp.where(
+                fruitless >= governor.demote_after,
+                jnp.ones((), dtype), gov[M.STAGNATED])
             action = jnp.where(stag > gov[M.STAGNATED],
                                jnp.asarray(M.ACTION_STAGNATED, dtype),
                                was_due)

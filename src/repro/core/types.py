@@ -64,24 +64,6 @@ HALO_TAG = "halo_xchg"
 REDUCE_TAG = "glred_hop"
 
 
-# ``lax.optimization_barrier`` has no batching rule (jax <= 0.4.x), which
-# would break the batched multi-RHS solvers (repro.core.batched vmaps the
-# per-column programs over the s-axis).  The barrier is semantically
-# transparent to vmap — a batched barrier is just a barrier on the batched
-# array — so declare exactly that.
-@jax.custom_batching.custom_vmap
-def _opt_barrier(dots: jax.Array) -> jax.Array:
-    return jax.lax.optimization_barrier(dots)
-
-
-@_opt_barrier.def_vmap
-def _opt_barrier_vmap(axis_size, in_batched, dots):
-    # Recurse through _opt_barrier (not the raw primitive) so nested
-    # vmaps peel one batch axis at a time instead of re-hitting the
-    # missing batching rule.
-    return _opt_barrier(dots), in_batched[0]
-
-
 def dot_block_rows(mat: jax.Array, vec: jax.Array) -> jax.Array:
     """The fused dot block (K, N) x (N,) -> (K,) as an elementwise
     product + trailing-axis reduction instead of ``mat @ vec``.
@@ -248,7 +230,7 @@ class SolverOps:
                 # Single-device: nothing to combine, but the barrier (a)
                 # marks the issue site for the tracer and (b) keeps XLA
                 # from folding the handle into its consumer.
-                return _opt_barrier(partials)
+                return jax.lax.optimization_barrier(partials)
             return self.combine_partials(partials)
 
     def wait(self, dots: jax.Array, advanced: int = 0) -> jax.Array:
@@ -302,7 +284,8 @@ class SolverOps:
 
         def wait(dots, advanced=0, _wait=dot_block_wait):
             with jax.named_scope(GLRED_WAIT_TAG):
-                return _opt_barrier(_wait(dots, advanced=advanced))
+                return jax.lax.optimization_barrier(
+                    _wait(dots, advanced=advanced))
 
         return SolverOps(
             apply_a=apply_a,

@@ -2,36 +2,38 @@
 (DESIGN.md §12) — the irregular counterpart of ``stencil_spmv``.
 
 Storage is dense-rectangular: ``cols``/``vals`` are (R, W) with W =
-max-nnz-per-row and zero-valued padding, so every load is a contiguous
-(BR, W) tile — CSR's ragged row pointers never reach the kernel.  The
-irregularity is confined to ONE gather per tile: ``x[cols_tile]``, with
-``x`` held resident in VMEM for the whole grid (the per-shard vectors of
-the solver path are a few MB — domain decomposition already bounded
-them).  After the gather the reduction is a dense (BR, W) multiply +
-small-axis sum on the VPU.
+max-nnz-per-row and zero-valued padding, so CSR's ragged row pointers
+never reach the kernel.  The one irregular access, ``x[cols]``, is an
+XLA gather in front of the kernel: Mosaic lowers no general gather from
+a VMEM-resident vector (only within-vreg 2-D gathers), so the kernel
+cannot hold ``x`` and gather it itself.  The wrapper hands the kernel
+the gathered values and ``vals`` slot-major, (W, R): every tile is a
+lane-dense (W, BR) block, and the row sum is an explicit add chain over
+its W sublane rows (the ``linalg.sparse.ell_rowsum`` order, so kernel
+and jnp applies round alike) into a lane-major (1, BR) output block.
 
-The gather is the TPU cost center: Mosaic lowers it to dynamic VMEM
-loads, which is why the wrapper (ops.py) keeps rows RCM-ordered — the
-partitioner's bandwidth reduction (``repro.linalg.partition``) makes
-consecutive rows hit near-consecutive x slots, the gather-locality
-equivalent of the stencil kernel's contiguous halo planes.  Off-TPU the
-kernel runs in interpret mode (the repo-wide validation vehicle); the
-pure-jnp oracle is ``kernels.ref.ell_spmv_ref``.
+The wrapper (ops.py) keeps rows RCM-ordered — the partitioner's
+bandwidth reduction (``repro.linalg.partition``) makes consecutive rows
+hit near-consecutive x slots, which keeps the gather's HBM reads local.
+Off-TPU the kernel runs in interpret mode (the repo-wide validation
+vehicle); the pure-jnp oracle is ``kernels.ref.ell_spmv_ref``.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 
-def _ell_spmv_kernel(x_ref, cols_ref, vals_ref, o_ref):
-    x = x_ref[...]                          # (NX,) resident vector
-    cols = cols_ref[...]                    # (BR, W) int32
-    vals = vals_ref[...]                    # (BR, W)
-    gathered = x[cols]                      # the one irregular access
-    o_ref[...] = (vals * gathered.astype(vals.dtype)).sum(axis=1)
+def _ell_spmv_kernel(xg_ref, vals_ref, o_ref):
+    xg = xg_ref[...]                        # (W, BR) gathered x, slot-major
+    vals = vals_ref[...]                    # (W, BR)
+    acc = vals[0:1] * xg[0:1]
+    for s in range(1, vals.shape[0]):
+        acc = acc + vals[s:s + 1] * xg[s:s + 1]
+    o_ref[...] = acc                        # (1, BR)
 
 
 def ell_spmv(
@@ -39,30 +41,27 @@ def ell_spmv(
     cols: jax.Array,
     vals: jax.Array,
     *,
-    block_r: int = 256,
+    block_r: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """y[r] = sum_s vals[r, s] * x[cols[r, s]] over (R, W) ELL slots.
 
-    ``R`` must be a multiple of ``block_r`` (ops.py pads with zero-value
-    rows, exact by construction).  ``x`` may be LONGER than R — the
-    distributed path passes the extended local vector [own | halo]
-    (``repro.linalg.partition.apply_local``).
+    ``R`` must be a multiple of ``block_r``, itself a multiple of 128
+    (ops.py pads with zero-value rows, exact by construction).  ``x``
+    may be LONGER than R — the distributed path passes the extended
+    local vector [own | halo] (``repro.linalg.partition.apply_local``).
     """
     r, w = cols.shape
     assert vals.shape == (r, w), (vals.shape, cols.shape)
     assert r % block_r == 0, (r, block_r)
-    nb = r // block_r
-    nx = x.shape[0]
-    return pl.pallas_call(
+    xg = x[cols.T].astype(vals.dtype)       # (W, R): the one gather
+    tile = pl.BlockSpec((w, block_r), lambda i: (np.int32(0), i))
+    out = pl.pallas_call(
         _ell_spmv_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((nx,), lambda i: (0,)),          # x resident
-            pl.BlockSpec((block_r, w), lambda i: (i, 0)),
-            pl.BlockSpec((block_r, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_r,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((r,), vals.dtype),
+        grid=(r // block_r,),
+        in_specs=[tile, tile],
+        out_specs=pl.BlockSpec((1, block_r), lambda i: (np.int32(0), i)),
+        out_shape=jax.ShapeDtypeStruct((1, r), vals.dtype),
         interpret=interpret,
-    )(x, cols, vals)
+    )(xg, vals.T)
+    return out[0]

@@ -35,6 +35,14 @@ only the dot partial summation ORDER (documented tight-tail behaviour,
 same policy as DESIGN.md §12); all row updates stay bitwise regardless
 of tiling.
 
+On TPU: ``idx``/``scal`` are scalar-prefetch (SMEM) operands and every
+vector is a lane-major (1, N) row read and written through the refs, so
+Mosaic lowers the single-tile kernel for the stencil and diagonal
+operators wherever that tile fits scoped VMEM (a 256x256 2D stencil on
+v5e).  Beyond it the build raises :class:`FusedSlabTooLarge` before
+compiling; tiling full-size problems is ROADMAP A3.  The ELL plug-in's
+gather has no Mosaic lowering and runs in interpret mode only.
+
 The state slab is input/output-aliased (``input_output_aliases``), so on
 TPU the iteration updates the slab in place — no per-iteration state
 copy; ``donate_argnums`` at the jit boundaries of the slab programs
@@ -49,7 +57,9 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # ---------------------------------------------------------------- layout --
@@ -179,6 +189,15 @@ def tel_layout(l: int) -> dict[str, int]:
 
 # ------------------------------------------------------------ SPMV tiles --
 
+_I0 = np.int32(0)       # an int32 block index under x64 too
+
+
+def _row(v: jax.Array) -> jax.Array:
+    """A vector as one lane-major (1, len) row — the kernel's layout for
+    every vector operand and value."""
+    return v.reshape(1, -1)
+
+
 @dataclasses.dataclass(frozen=True)
 class FusedSpmv:
     """Operator plug-in for the superkernel.
@@ -186,9 +205,9 @@ class FusedSpmv:
     ``prepare(z_top)`` runs OUTSIDE the kernel (halo exchange, reshape)
     and returns the extra operand arrays; ``specs(block_n, n)`` their
     BlockSpecs; ``tile(extras, z_tile, pid, block_n)`` computes the
-    az row tile inside the kernel — written to evaluate exactly the same
-    jnp expression as the unfused ``ops.apply_a`` so row updates stay
-    bitwise (tests/test_fused_iter.py).
+    (1, block_n) az row tile inside the kernel — written to evaluate
+    exactly the same jnp expression as the unfused ``ops.apply_a`` so
+    row updates stay bitwise (tests/test_fused_iter.py).
     """
 
     prepare: Callable[[jax.Array], tuple]
@@ -201,63 +220,96 @@ def resident_spmv(expr: Callable[[jax.Array], jax.Array],
                   ext_len: int) -> FusedSpmv:
     """Stencil-style SPMV: the (halo-extended) operand vector is VMEM-
     resident for the whole grid; each tile slices its rows out of the
-    full stencil evaluation (a single-tile grid makes the slice the
-    identity — the bitwise-default configuration)."""
+    full stencil evaluation (a single-tile grid — the bitwise default
+    and the only one Mosaic lowers — takes it whole)."""
 
     def specs(block_n: int, n: int):
-        return [pl.BlockSpec((ext_len,), lambda i: (0,))]
+        return [pl.BlockSpec((1, ext_len), lambda i, *_: (_I0, _I0))]
 
     def tile(extras, z_tile, pid, block_n):
-        az_full = expr(extras[0][...])
-        return jax.lax.dynamic_slice(az_full, (pid * block_n,), (block_n,))
+        az_full = _row(expr(extras[0][...]))
+        if az_full.shape[1] == block_n:
+            return az_full
+        return jax.lax.dynamic_slice(az_full, (_I0, pid * block_n),
+                                     (1, block_n))
 
-    return FusedSpmv(prepare=lambda z: (prepare(z),), specs=specs, tile=tile)
+    return FusedSpmv(prepare=lambda z: (_row(prepare(z)),), specs=specs,
+                     tile=tile)
 
 
 def diagonal_spmv(d: jax.Array) -> FusedSpmv:
     """A = diag(d): az is elementwise — the tile needs no halo at all."""
 
     def specs(block_n: int, n: int):
-        return [pl.BlockSpec((block_n,), lambda i: (i,))]
+        return [pl.BlockSpec((1, block_n), lambda i, *_: (_I0, i))]
 
     def tile(extras, z_tile, pid, block_n):
         return extras[0][...].astype(z_tile.dtype) * z_tile
 
-    return FusedSpmv(prepare=lambda z: (d,), specs=specs, tile=tile)
+    return FusedSpmv(prepare=lambda z: (_row(d),), specs=specs, tile=tile)
 
 
 def ell_spmv(cols: jax.Array, vals: jax.Array,
              prepare: Callable[[jax.Array], jax.Array],
              ext_len: int) -> FusedSpmv:
     """Unstructured padded-row ELL rows: cols/vals tile with the rows,
-    the (halo-extended) x stays resident for the one gather per tile
-    (same structure as ``kernels.ell_spmv``); the row sum uses the
-    explicit add chain of ``linalg.sparse.ell_rowsum`` so local and
-    distributed applies keep rounding identically (DESIGN.md §12)."""
+    the (halo-extended) x stays resident for the one gather per tile;
+    the row sum uses the explicit add chain of
+    ``linalg.sparse.ell_rowsum`` so local and distributed applies keep
+    rounding identically (DESIGN.md §12).  Mosaic lowers no general
+    gather, so this plug-in runs in interpret mode only."""
     w = cols.shape[1]
 
     def specs(block_n: int, n: int):
         return [
-            pl.BlockSpec((ext_len,), lambda i: (0,)),
-            pl.BlockSpec((block_n, w), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, w), lambda i: (i, 0)),
+            pl.BlockSpec((1, ext_len), lambda i, *_: (_I0, _I0)),
+            pl.BlockSpec((block_n, w), lambda i, *_: (i, _I0)),
+            pl.BlockSpec((block_n, w), lambda i, *_: (i, _I0)),
         ]
 
     def tile(extras, z_tile, pid, block_n):
-        x = extras[0][...]
+        x = extras[0][...][0]
         cols_t = extras[1][...]
         vals_t = extras[2][...].astype(z_tile.dtype)
         gathered = x[cols_t].astype(vals_t.dtype)
         acc = vals_t[..., 0] * gathered[..., 0]
         for s in range(1, w):
             acc = acc + vals_t[..., s] * gathered[..., s]
-        return acc
+        return _row(acc)
 
-    return FusedSpmv(prepare=lambda z: (prepare(z), cols, vals),
+    return FusedSpmv(prepare=lambda z: (_row(prepare(z)), cols, vals),
                      specs=specs, tile=tile)
 
 
 # ---------------------------------------------------------------- kernel --
+
+# Mosaic's default scoped-VMEM limit for one kernel on TPU v5e: the
+# compiler refuses a kernel whose blocks and temporaries need more.
+VMEM_LIMIT_BYTES = 16 * 2**20
+_SUBLANES = 8           # a VMEM tile holds rows in groups of 8
+
+
+class FusedSlabTooLarge(ValueError):
+    """The superkernel's slab tile does not fit the chip's VMEM.
+
+    Raised while tracing, before anything compiles: a fused iteration
+    never falls back to the unfused path or to the interpreter.  Tiling
+    the slab for full-size problems is future work (ROADMAP A3)."""
+
+
+def tile_vmem_bytes(nv: int, block_n: int, n_tiles: int, dsize: int,
+                    extra_blocks: Sequence[Sequence[int]]) -> int:
+    """Scoped VMEM the kernel's blocks take: the (NV, block_n) slab tile
+    in and out plus every side-input block, rows rounded up to the
+    8-sublane tile, double-buffered when the grid has more than one
+    step.  (Measured against Mosaic for v5e: a (14, 131072) float32
+    single tile with its resident stencil row asks 20.11M of 16M.)"""
+    pad = lambda rows: -(-rows // _SUBLANES) * _SUBLANES
+    words = 2 * pad(nv) * block_n
+    words += sum(pad(shape[0]) * int(np.prod(shape[1:]))
+                 for shape in extra_blocks)
+    return (1 if n_tiles == 1 else 2) * words * dsize
+
 
 def build_fused_iteration(
     layout: SlabLayout,
@@ -276,6 +328,11 @@ def build_fused_iteration(
     dot-block partials — with the slab read once and written once per
     tile (``input_output_aliases`` pins S' to S's buffer).
 
+    ``idx``/``scal`` ride as scalar-prefetch (SMEM) operands; the kernel
+    reads and writes slab rows through the refs at those indices.  Every
+    read happens before the first write, so the result is the same
+    whether or not the runtime aliases the slab's in and out blocks.
+
     ``inv_diag`` enables the pointwise (Jacobi) preconditioner tile;
     None means identity.  Block-structured preconditioners have no fused
     path (their block solve is not pointwise) — ``fused_iteration_factory``
@@ -290,35 +347,36 @@ def build_fused_iteration(
         raise ValueError(f"unknown recurrence {layout.recurrence!r} "
                          "(want 'ghysels' or 'stable')")
     stable = layout.recurrence == "stable"
+    x_row, p_row = layout.x_row, layout.p_row
 
-    def kernel(s_ref, idx_ref, scal_ref, *rest):
+    def kernel(idx_ref, scal_ref, s_ref, *rest):
         *extra_refs, o_ref, acc_ref = rest
         if has_prec:
             *extra_refs, prec_ref = extra_refs
-        s = s_ref[...]                       # (NV, BN) — the one slab read
-        idx = idx_ref[...]
-        scal = scal_ref[...]
         pid = pl.program_id(0)
-        bn = s.shape[1]
+        bn = s_ref.shape[1]
 
-        def get(row):
-            return jax.lax.dynamic_index_in_dim(s, row, 0, keepdims=False)
+        def ix(name, k=0):
+            return idx_ref[IX[name] + k]
 
-        def put(out, row, vec):
-            return jax.lax.dynamic_update_index_in_dim(out, vec, row, axis=0)
+        def sc(name, k=0):
+            return scal_ref[IS[name] + k]
 
-        late = idx[IX["f_late"]] != 0
-        z_top = get(idx[IX["z_top"]])
-        u_i = get(idx[IX["u_i"]])
-        u_im1 = get(idx[IX["u_im1"]])
+        def get(row):                        # (1, BN) slab row
+            return s_ref[pl.ds(row, 1), :]
+
+        late = ix("f_late") != 0
+        z_top = get(ix("z_top"))
+        u_i = get(ix("u_i"))
+        u_im1 = get(ix("u_im1"))
 
         # ---- (K1) SPMV + pointwise preconditioner ------------------------
         az = spmv.tile(extra_refs, z_top, pid, bn)
-        u_new0 = az - scal[IS["sig_i"]] * u_i
+        u_new0 = az - sc("sig_i") * u_i
         u_new = jnp.where(
             late,
-            (u_new0 - scal[IS["gam_new"]] * u_i
-             - scal[IS["d2"]] * u_im1) / scal[IS["dlt_safe"]],
+            (u_new0 - sc("gam_new") * u_i
+             - sc("d2") * u_im1) / sc("dlt_safe"),
             u_new0)
         if stable:
             # Coupled recurrence (arXiv:1902.03100, DESIGN.md §18): the
@@ -330,100 +388,105 @@ def build_fused_iteration(
             z_fill = z_new
         else:
             z_new0 = prec_ref[...] * u_new0 if has_prec else u_new0
-            zl_im1 = get(idx[IX["zl_im1"]])
+            zl_im1 = get(ix("zl_im1"))
             z_new = jnp.where(
                 late,
-                (z_new0 - scal[IS["gam_new"]] * z_top
-                 - scal[IS["d2"]] * zl_im1) / scal[IS["dlt_safe"]],
+                (z_new0 - sc("gam_new") * z_top
+                 - sc("d2") * zl_im1) / sc("dlt_safe"),
                 z_new0)
             z_fill = z_new0
 
-        out = s
         # ---- pipeline-fill copies (lines 5-7) ----------------------------
-        for k in range(l):
-            row = idx[IX["fill"] + k]
-            fill_k = idx[IX["f_fill"] + k] != 0
-            out = put(out, row, jnp.where(fill_k, z_fill, get(row)))
+        fills = [jnp.where(ix("f_fill", k) != 0, z_fill, get(ix("fill", k)))
+                 for k in range(l)]
 
         # ---- (K4) stable basis recurrences (masked late) -----------------
         recs = []
         for k in range(l):
-            zk1 = get(idx[IX["rec_a"] + k])
-            zm1 = get(idx[IX["rec_b"] + k])
-            zm2 = get(idx[IX["rec_c"] + k])
-            rec = (zk1 + scal[IS["c1"] + k] * zm1
-                   - scal[IS["d2"]] * zm2) / scal[IS["dlt_safe"]]
-            val = jnp.where(late, rec, get(idx[IX["rec_w"] + k]))
-            recs.append(val)
-            out = put(out, idx[IX["rec_w"] + k], val)
+            zk1 = get(ix("rec_a", k))
+            zm1 = get(ix("rec_b", k))
+            zm2 = get(ix("rec_c", k))
+            rec = (zk1 + sc("c1", k) * zm1
+                   - sc("d2") * zm2) / sc("dlt_safe")
+            recs.append(jnp.where(late, rec, get(ix("rec_w", k))))
 
-        out = put(out, idx[IX["z_w"]], z_new)
-        out = put(out, idx[IX["u_w"]], u_new)
-
-        # ---- (K5) local dot-block partials, accumulated in VMEM ----------
+        # ---- (K5) local dot-block partials -------------------------------
         # Rows i-2l+1..i+1 of G column i+1: the ZK^(0) V-range (last entry
         # freshly recurred), the ZK^(l) Z-range, and z_{i+1} itself.
-        rows = [get(idx[IX["mat_v"] + t]) for t in range(l)] + [recs[0]]
-        rows += [get(idx[IX["mat_z"] + t]) for t in range(l - 1)] + [z_new]
-        mat = jnp.stack(rows)                # (2l+1, BN)
+        rows = [get(ix("mat_v", t)) for t in range(l)] + [recs[0]]
+        rows += [get(ix("mat_z", t)) for t in range(l - 1)] + [z_new]
+        mat = jnp.concatenate(rows, axis=0)  # (2l+1, BN)
 
         # ---- (K6) solution/search-direction updates ----------------------
-        x_old = s[layout.x_row]
-        p_old = s[layout.p_row]
-        p_first = s[0] / scal[IS["eta0_safe"]]
-        p_new = (get(idx[IX["p_im"]])
-                 - scal[IS["d_prev"]] * p_old) / scal[IS["eta_new_safe"]]
-        x_new = x_old + scal[IS["zet_prev"]] * p_old
-        do_upd = idx[IX["f_upd"]] != 0
-        is_first = idx[IX["f_first"]] != 0
-        out = out.at[layout.x_row].set(jnp.where(do_upd, x_new, x_old))
-        out = out.at[layout.p_row].set(
-            jnp.where(is_first, p_first,
-                      jnp.where(do_upd, p_new, p_old)))
+        x_old = s_ref[x_row:x_row + 1, :]
+        p_old = s_ref[p_row:p_row + 1, :]
+        p_first = s_ref[0:1, :] / sc("eta0_safe")
+        p_new = (get(ix("p_im"))
+                 - sc("d_prev") * p_old) / sc("eta_new_safe")
+        x_new = x_old + sc("zet_prev") * p_old
+        do_upd = ix("f_upd") != 0
+        is_first = ix("f_first") != 0
 
-        o_ref[...] = out                     # the one slab write
+        # ---- the one slab write, in the unfused path's order -------------
+        o_ref[...] = s_ref[...]
+        for k in range(l):
+            o_ref[pl.ds(ix("fill", k), 1), :] = fills[k]
+        for k in range(l):
+            o_ref[pl.ds(ix("rec_w", k), 1), :] = recs[k]
+        o_ref[pl.ds(ix("z_w"), 1), :] = z_new
+        o_ref[pl.ds(ix("u_w"), 1), :] = u_new
+        o_ref[x_row:x_row + 1, :] = jnp.where(do_upd, x_new, x_old)
+        o_ref[p_row:p_row + 1, :] = jnp.where(
+            is_first, p_first, jnp.where(do_upd, p_new, p_old))
 
         # Per-tile partials; the wrapper chain-sums tiles (a single tile
         # — the bitwise default — makes the sum the identity).  The
         # expression mirrors types.dot_block_rows exactly: a trailing-
         # axis reduce is bitwise-stable across the interpreter and vmap
         # where a dot_general is not.
-        acc_ref[...] = (mat * u_new[None, :]).sum(axis=1)[:, None]
+        acc_ref[...] = (mat * u_new).sum(axis=1, keepdims=True)
 
     def fiter(S: jax.Array, idx: jax.Array, scal: jax.Array):
         n = S.shape[1]
         bn = n if block_n is None else block_n
         assert n % bn == 0, (n, bn)
         nb = n // bn
-        dtype = S.dtype
+        dtype = jnp.dtype(S.dtype)
         z_top = jax.lax.dynamic_index_in_dim(S, idx[IX["z_top"]], 0,
                                              keepdims=False)
         extras = spmv.prepare(z_top)
-        in_specs = [
-            pl.BlockSpec((nv, bn), lambda i: (0, i)),       # S tiles
-            pl.BlockSpec((IX["size"],), lambda i: (0,)),
-            pl.BlockSpec((IS["size"],), lambda i: (0,)),
-            *spmv.specs(bn, n),
-        ]
-        inputs = [S, idx, scal, *extras]
+        side = spmv.specs(bn, n)
         if has_prec:
-            in_specs.append(pl.BlockSpec((bn,), lambda i: (i,)))
-            inputs.append(inv_diag.astype(dtype))
+            side.append(pl.BlockSpec((1, bn), lambda i, *_: (_I0, i)))
+            extras = (*extras, _row(inv_diag.astype(dtype)))
+        if not interpret:
+            need = tile_vmem_bytes(nv, bn, nb, dtype.itemsize,
+                                   [sp.block_shape for sp in side])
+            if need > VMEM_LIMIT_BYTES:
+                raise FusedSlabTooLarge(
+                    f"fused iteration: a ({nv}, {bn}) {dtype.name} slab "
+                    f"tile needs {need / 2**20:.1f} MiB of VMEM, over the "
+                    f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit (n={n}); "
+                    f"run unfused (fused_iteration=False)")
         out, acc = pl.pallas_call(
             kernel,
-            grid=(nb,),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((nv, bn), lambda i: (0, i)),
-                pl.BlockSpec((nd, 1), lambda i: (0, i)),
-            ],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(nb,),
+                in_specs=[pl.BlockSpec((nv, bn), lambda i, *_: (_I0, i)),
+                          *side],
+                out_specs=[
+                    pl.BlockSpec((nv, bn), lambda i, *_: (_I0, i)),
+                    pl.BlockSpec((nd, 1), lambda i, *_: (_I0, i)),
+                ],
+            ),
             out_shape=[
                 jax.ShapeDtypeStruct((nv, n), dtype),
                 jax.ShapeDtypeStruct((nd, nb), dtype),
             ],
-            input_output_aliases={0: 0},     # slab updates in place
+            input_output_aliases={2: 0},     # slab updates in place
             interpret=interpret,
-        )(*inputs)
+        )(idx.astype(jnp.int32), scal.astype(dtype), S, *extras)
         partials = acc[:, 0]
         for t in range(1, nb):               # static chain over tiles
             partials = partials + acc[:, t]

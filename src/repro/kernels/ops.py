@@ -2,9 +2,9 @@
 
 Each wrapper (a) pads shapes to kernel-friendly multiples (zero padding is
 exact for every kernel here), (b) picks TPU-aligned block shapes, and
-(c) falls back to ``interpret=True`` off-TPU so the same call sites work on
-this CPU container (system prompt: TPU is the TARGET, interpret mode is the
-validation vehicle).
+(c) compiles the kernel on TPU and interprets it on CPU (the tests' and
+CPU rehearsals' vehicle).  Any other platform raises: no kernel silently
+runs interpreted on a device.
 """
 
 from __future__ import annotations
@@ -23,7 +23,12 @@ from repro.kernels import stencil_spmv as _ss
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU and are interpreted on "
+            f"CPU only; platform {platform!r} has no kernel path")
+    return platform == "cpu"
 
 
 # ------------------------------------------------- fused-iteration factory --
@@ -139,7 +144,9 @@ def stencil2d5_apply(g: jax.Array, interpret: bool | None = None) -> jax.Array:
     bx = 8
     while bx * 2 <= min(nx, 256) and nx % (bx * 2) == 0:
         bx *= 2
-    nxp, nyp = _round_up(nx, bx), _round_up(ny, 128 if ny >= 128 else 8)
+    # lanes pad to the TPU's 128 (the interpreter takes any width)
+    nxp = _round_up(nx, bx)
+    nyp = _round_up(ny, 8 if interpret else 128)
     gp = jnp.pad(g, ((0, nxp - nx), (0, nyp - ny)))
     out = _ss.stencil2d5(gp, block_x=bx, interpret=interpret)
     return out[:nx, :ny]
@@ -168,9 +175,8 @@ def ell_spmv_apply(x: jax.Array, cols: jax.Array, vals: jax.Array,
     with zero-value slots — exact, since padded rows are sliced off."""
     interpret = _interpret_default() if interpret is None else interpret
     r, w = cols.shape
-    br = 8
-    while br * 2 <= min(r, 256) and r % (br * 2) == 0:
-        br *= 2
+    # the rank-1 output block must be a multiple of the 128-lane tile
+    br = min(512, _round_up(r, 128))
     rp = _round_up(r, br)
     colsp = jnp.pad(cols, ((0, rp - r), (0, 0)))
     valsp = jnp.pad(vals, ((0, rp - r), (0, 0)))
@@ -182,7 +188,7 @@ def ell_spmv_apply(x: jax.Array, cols: jax.Array, vals: jax.Array,
 def fused_dots(mat: jax.Array, vec: jax.Array, interpret: bool | None = None):
     interpret = _interpret_default() if interpret is None else interpret
     k, n = mat.shape
-    bn = min(16384, _round_up(n, 128))
+    bn = min(32768, _round_up(n, 128))
     npad = _round_up(n, bn)
     matp = jnp.pad(mat, ((0, 0), (0, npad - n)))
     vecp = jnp.pad(vec, (0, npad - n))
@@ -193,25 +199,22 @@ def fused_dots(mat: jax.Array, vec: jax.Array, interpret: bool | None = None):
 def fused_dots_mrhs(mat: jax.Array, vecs: jax.Array,
                     interpret: bool | None = None):
     """(K, N) x (N, S) -> (K, S): the slab dot block, mat streamed once for
-    all S right-hand sides (DESIGN.md §11).  Zero-pads N to a block
-    multiple and S to the TPU lane width off-interpret."""
+    all S right-hand sides (DESIGN.md §11).  The kernel takes the
+    right-hand sides lane-major (S, N); N zero-pads to a block multiple."""
     interpret = _interpret_default() if interpret is None else interpret
     k, n = mat.shape
-    s = vecs.shape[1]
-    bn = min(16384, _round_up(n, 128))
+    bn = min(32768, _round_up(n, 128))
     npad = _round_up(n, bn)
-    spad = _round_up(s, 8 if interpret else 128)
     matp = jnp.pad(mat, ((0, 0), (0, npad - n)))
-    vecsp = jnp.pad(vecs, ((0, npad - n), (0, spad - s)))
-    out = _fd.fused_dots_mrhs(matp, vecsp, block_n=bn, interpret=interpret)
-    return out[:, :s]
+    vecsp = jnp.pad(vecs.T, ((0, 0), (0, npad - n)))
+    return _fd.fused_dots_mrhs(matp, vecsp, block_n=bn, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def fused_axpy3(zk1, zm1, zm2, c1, c2, scale, interpret: bool | None = None):
     interpret = _interpret_default() if interpret is None else interpret
     (n,) = zk1.shape
-    bn = min(65536, _round_up(n, 128))
+    bn = min(65536, _round_up(n, 8 * _fa.LANES))
     npad = _round_up(n, bn)
     pad = lambda v: jnp.pad(v, (0, npad - n))
     out = _fa.fused_axpy3(

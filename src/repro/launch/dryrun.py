@@ -53,7 +53,6 @@ def run_cell(arch: str, shape_name: str, mesh, kv_seq_shard=False,
     cell = build_cell(arch, shape_name, mesh, kv_seq_shard=kv_seq_shard,
                       pure_dp=pure_dp, pipeline_l=pipeline_l)
     attn_mod.SPLIT_KV_AXIS = "model" if split_kv else None
-    attn_mod.SPLIT_KV_MESH = mesh if split_kv else None
     attn_mod.DECODE_UPCAST = not decode_bf16
     with jax.set_mesh(mesh):
         jitted = jax.jit(
@@ -64,7 +63,6 @@ def run_cell(arch: str, shape_name: str, mesh, kv_seq_shard=False,
         lowered = jitted.lower(*cell.args)
         compiled = lowered.compile()
     attn_mod.SPLIT_KV_AXIS = None
-    attn_mod.SPLIT_KV_MESH = None
     attn_mod.DECODE_UPCAST = True
     t1 = time.time()
 
@@ -132,7 +130,6 @@ def _compile_costs(arch, shape_name, mesh, depth_units, kv_seq_shard,
     old = model_mod.SCAN_UNROLL
     model_mod.SCAN_UNROLL = True
     attn_mod.SPLIT_KV_AXIS = "model" if split_kv else None
-    attn_mod.SPLIT_KV_MESH = mesh if split_kv else None
     attn_mod.DECODE_UPCAST = not decode_bf16
     moe_mod.CONSTRAIN_EP = moe_constrain
     try:
@@ -145,7 +142,6 @@ def _compile_costs(arch, shape_name, mesh, depth_units, kv_seq_shard,
     finally:
         model_mod.SCAN_UNROLL = old
         attn_mod.SPLIT_KV_AXIS = None
-        attn_mod.SPLIT_KV_MESH = None
         attn_mod.DECODE_UPCAST = True
         moe_mod.CONSTRAIN_EP = False
     cost = cost_analysis_dict(compiled)

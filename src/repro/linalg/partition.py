@@ -230,8 +230,7 @@ def halo_exchange(x_local: jax.Array, send_up: jax.Array,
     """
     hops, max_send = send_up.shape
     with jax.named_scope(HALO_TAG):
-        n = int(lax.psum(1, axis)) if not hasattr(lax, "axis_size") \
-            else lax.axis_size(axis)
+        n = lax.axis_size(axis)
         slabs = [x_local]
         from_prev, from_next = [], []
         for h in range(1, hops + 1):

@@ -215,11 +215,6 @@ def decode_attention_jnp(q, k_cache, v_cache, kv_len):
 # discipline applied to serving (DESIGN.md §8).  None = let GSPMD choose
 # (the baseline the §Perf hillclimb measures against).
 SPLIT_KV_AXIS: str | None = None
-# Older jax (<= 0.4.x) has no meshless jax.shard_map(axis_names=...); its
-# experimental shard_map needs the concrete mesh for the partial-auto
-# form.  Drivers that flip SPLIT_KV_AXIS (launch/dryrun) set this
-# alongside it; newer jax ignores it.
-SPLIT_KV_MESH = None
 
 
 def split_kv_decode(q, k_cache, v_cache, kv_len, axis: str):
@@ -255,21 +250,8 @@ def split_kv_decode(q, k_cache, v_cache, kv_len, axis: str):
     from jax.sharding import PartitionSpec as P
     in_specs = (P(), P(None, axis, None, None), P(None, axis, None, None),
                 P())
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            local, axis_names={axis}, in_specs=in_specs, out_specs=P(),
-        )(q, k_cache, v_cache, kv_len)
-    # jax 0.4.x fallback: experimental shard_map, partial-auto over the
-    # remaining mesh axes (needs the concrete mesh — SPLIT_KV_MESH).
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if SPLIT_KV_MESH is None:
-        raise RuntimeError(
-            "split-KV decode on this jax version needs "
-            "repro.models.attention.SPLIT_KV_MESH set to the active mesh")
-    auto = frozenset(SPLIT_KV_MESH.axis_names) - {axis}
-    return _shard_map(
-        local, mesh=SPLIT_KV_MESH, in_specs=in_specs, out_specs=P(),
-        check_rep=False, auto=auto,
+    return jax.shard_map(
+        local, axis_names={axis}, in_specs=in_specs, out_specs=P(),
     )(q, k_cache, v_cache, kv_len)
 
 

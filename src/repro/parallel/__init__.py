@@ -25,7 +25,6 @@ from repro.parallel.distributed import (
     distributed_solve_batched,
     make_solver_mesh,
     partitioned_solver_ops,
-    shard_map_compat,
 )
 
 __all__ = [
@@ -38,5 +37,4 @@ __all__ = [
     "distributed_solve_batched",
     "make_solver_mesh",
     "partitioned_solver_ops",
-    "shard_map_compat",
 ]

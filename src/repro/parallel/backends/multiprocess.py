@@ -84,14 +84,9 @@ def _configure_collectives() -> None:
     need the gloo TCP collectives backend for cross-host ppermute/psum
     (the default shared-memory CPU collectives cannot cross hosts).
     Setting the config after initialization is a no-op, hence this runs
-    first — idempotent, and tolerant of jax versions that only read the
-    JAX_CPU_COLLECTIVES_IMPLEMENTATION env var (the launcher sets that
-    too, scripts/multiprocess_parity.py).
+    first (idempotent).
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:      # pragma: no cover - very old/new jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _ensure_initialized(**kwargs) -> None:
@@ -157,14 +152,24 @@ class MultiprocessBackend(ShardMapBackend):
 
     # ------------------------------------------------- wire introspection --
     def hop_wire(self) -> str:
-        """What carries one tagged ladder hop between ranks: ``"nccl"``
-        (GPU ranks), ``"gloo"`` (CPU ranks over TCP), or
-        ``"intra-process"`` when the whole mesh lives in this process
-        (single-controller degradation — no wire at all)."""
+        """What carries one tagged ladder hop between ranks: ``"ici"``
+        (TPU ranks in one slice, the chips' own interconnect), ``"dcn"``
+        (TPU ranks across slices), ``"nccl"`` (GPU ranks), ``"gloo"``
+        (CPU ranks over TCP), or ``"intra-process"`` when the whole mesh
+        lives in this process (single-controller degradation — no wire
+        at all)."""
         if self.n_processes <= 1:
             return "intra-process"
-        platforms = {d.platform for d in self.mesh.devices.flat}
-        return "nccl" if platforms & {"gpu", "cuda", "rocm"} else "gloo"
+        devs = list(self.mesh.devices.flat)
+        platforms = {d.platform for d in devs}
+        if platforms == {"tpu"}:
+            slices = {getattr(d, "slice_index", 0) for d in devs}
+            return "dcn" if len(slices) > 1 else "ici"
+        if platforms & {"gpu", "cuda", "rocm"}:
+            return "nccl"
+        if platforms == {"cpu"}:
+            return "gloo"
+        raise ValueError(f"no known hop wire for platforms {platforms}")
 
     def cross_process_edges(self) -> int:
         """Ring edges of the hop ladder that cross a process boundary —

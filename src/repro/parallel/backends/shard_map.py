@@ -36,7 +36,6 @@ from repro.parallel.distributed import (
     distributed_solve_batched,
     make_solver_mesh,
     partitioned_solver_ops,
-    shard_map_compat,
 )
 from repro.parallel.backends.base import ReductionBackend
 from repro.parallel.reduction import (StagedConfig, oracle_solver_ops,
@@ -152,9 +151,9 @@ class ShardMapBackend(ReductionBackend):
         status_specs = SlabStatus(running=P(), converged=P(), iters=P())
 
         def staged(fn, in_specs, out_specs, donate=()):
-            wrapped = shard_map_compat(fn, mesh=self.mesh,
-                                       in_specs=in_specs,
-                                       out_specs=out_specs)
+            wrapped = jax.shard_map(fn, mesh=self.mesh,
+                                    in_specs=in_specs,
+                                    out_specs=out_specs, check_vma=False)
             # donate=(1,) on chunk/inject: the slab state is consumed and
             # replaced every call, so its sharded buffers alias through
             # the jit boundary instead of copying (DESIGN.md §13).
@@ -215,10 +214,9 @@ class ShardMapBackend(ReductionBackend):
 
         b_spec = P(self.axis) if b_spec is None else b_spec
         arr_specs = jax.tree.map(lambda _: P(self.axis), arrays)
-        wrapped = shard_map_compat(
+        wrapped = jax.shard_map(
             run, mesh=self.mesh, in_specs=(b_spec, arr_specs),
-            out_specs=P(),
-        )
+            out_specs=P(), check_vma=False)
         return wrapped, arrays
 
     def run(self, fn, op, b, prec=None, b_spec=None) -> Any:

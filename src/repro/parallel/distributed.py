@@ -60,27 +60,6 @@ from repro.linalg.preconditioners import BlockJacobi, IdentityPrec, JacobiPrec
 from repro.linalg.sparse import SparseOp
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` with ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  Both
-    checks are disabled: the solver outputs mix sharded (x) and replicated
-    (scalars/history) results that the checker cannot infer through
-    ``lax.while_loop``.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 def make_solver_mesh(n_shards: int | None = None, devices=None) -> Mesh:
     """1-D mesh over all (or the first ``n_shards``) devices.
 
@@ -104,10 +83,7 @@ def _halo_first_dim(g: jax.Array, axis: str) -> tuple[jax.Array, jax.Array]:
     zeros where no neighbour exists — which is exactly the homogeneous
     Dirichlet boundary condition of the operators.
     """
-    # lax.axis_size is not present in every jax version; psum of a Python
-    # scalar folds to the static axis size under both old and new jax.
-    n = int(lax.psum(1, axis)) if not hasattr(lax, "axis_size") \
-        else lax.axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         z = jnp.zeros_like(g[:1])
         return z, z
@@ -521,8 +497,8 @@ def distributed_solve_batched(
         return batched_mod.solve_batched(ops, B_local, method, **kwargs)
 
     arr_specs = jax.tree.map(lambda _: P(axis), arrays)
-    inner = shard_map_compat(
-        run, mesh=mesh, in_specs=(P(axis, None), arr_specs),
+    inner = jax.shard_map(
+        run, mesh=mesh, in_specs=(P(axis, None), arr_specs), check_vma=False,
         out_specs=batched_result_specs(
             axis, telemetry=bool(kwargs.get("telemetry_cap", 0)),
             governor=kwargs.get("governor") is not None),
@@ -627,7 +603,7 @@ def distributed_checkpointed_solve(
                                              tiled=True) if is_vec else v,
             st, vec)
 
-    sm = partial(shard_map_compat, mesh=mesh)
+    sm = partial(jax.shard_map, mesh=mesh, check_vma=False)
     init_j = jax.jit(sm(_init, in_specs=(P(axis), P(axis), arr_specs),
                         out_specs=st_specs))
     seg_j = jax.jit(sm(_seg, in_specs=(P(axis), st_specs, arr_specs),
@@ -704,9 +680,9 @@ def distributed_solve(
         governor=P() if kwargs.get("governor") is not None else None,
     )
     arr_specs = jax.tree.map(lambda _: P(axis), arrays)
-    inner = shard_map_compat(
+    inner = jax.shard_map(
         run, mesh=mesh, in_specs=(P(axis), arr_specs), out_specs=out_specs,
-    )
+        check_vma=False)
 
     def fn(b, arrays):
         return post(inner(pre(b), arrays))

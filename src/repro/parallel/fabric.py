@@ -220,7 +220,8 @@ def launch_fabric(
 
     ``child_argv(coordinator, process_id)`` builds rank k's argv; every
     rank is spawned with ``env`` (default: the launcher's environment)
-    plus a per-rank ``ENV_HEARTBEAT`` file path (stdout+stderr merged,
+    with ``JAX_PLATFORMS=cpu`` forced, plus a per-rank
+    ``ENV_HEARTBEAT`` file path (stdout+stderr merged,
     text mode).  Children that call :func:`touch_heartbeat` at progress
     points get per-rank "last heartbeat N s ago" lines in every fabric
     error — a surviving rank whose heartbeat is older than
@@ -243,7 +244,11 @@ def launch_fabric(
     if num_processes < 1:
         raise ValueError(f"num_processes must be >= 1, got {num_processes}")
     last_outputs: list[str] = []
-    base_env = dict(os.environ if env is None else env)
+    # Ranks are CPU/gloo processes by design.  Pin their platform: on a
+    # host with an accelerator, children inheriting the parent's
+    # environment would each try to open the chip the parent holds.
+    base_env = {**(os.environ if env is None else env),
+                "JAX_PLATFORMS": "cpu"}
     for attempt in range(1, max_port_retries + 2):
         coordinator = pick_coordinator(host)
         hb_dir = tempfile.mkdtemp(prefix="repro-fabric-hb-")

@@ -285,6 +285,16 @@ def oracle_start(mat: jax.Array, vec: jax.Array,
                          f"({n} % {p})")
     wire = cfg.wire_dtype(vec.dtype)
     nl = n // p
+    if mat.shape[0] == 1:
+        # A one-row block is a vector dot.  A shard's (1, nl) block
+        # compiles to a reduce over a 1-D vector; slicing (1, n) here
+        # instead keeps a (1, 128)-tiled 2-D operand on the TPU, which
+        # sums in another order.  Slice the 1-D vectors.
+        row = mat[0]
+        parts = [dot_block_rows(row[None, r * nl:(r + 1) * nl],
+                                vec[r * nl:(r + 1) * nl]).astype(wire)
+                 for r in range(p)]
+        return jnp.stack(parts, axis=0)
     mats = mat.reshape(mat.shape[0], p, nl)
     vecs = vec.reshape(p, nl)
     parts = [dot_block_rows(mats[:, r, :], vecs[r]).astype(wire)

@@ -51,17 +51,14 @@ from repro.obs.metrics import MetricsRegistry
 from repro.serve.batcher import SlabKey, SolveRequest
 from repro.serve.errors import WorkerFault
 
-try:                                     # jax >= 0.4.14
-    from jax.errors import JaxRuntimeError as _JaxRuntimeError
-except ImportError:                      # pragma: no cover - old jax
-    class _JaxRuntimeError(Exception):
-        """Placeholder: never raised when jax lacks JaxRuntimeError."""
-
 # Exceptions the scheduler treats as "this worker's backing
 # program/process died" (tear down + resubmit) rather than a scheduler
-# bug (propagate).  Injected chaos faults raise WorkerFault directly;
-# a dead fabric rank surfaces as a jax runtime error at dispatch/poll.
-WORKER_FAULT_TYPES = (WorkerFault, _JaxRuntimeError)
+# bug (propagate).  Only a typed ``WorkerFault`` qualifies — raised by
+# injected chaos faults and by whatever supervises a backing process.  A
+# jax runtime error (a compile failure, a device fault on the chip)
+# propagates: resubmitting its requests would shed them and let a serve
+# run "succeed" over a broken device.
+WORKER_FAULT_TYPES = (WorkerFault,)
 
 
 class StealEvent(NamedTuple):

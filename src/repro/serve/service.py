@@ -139,6 +139,11 @@ class SolverService:
                   each column's donated state — zero extra collectives,
                   zero host transfers, bitwise-invisible to the
                   arithmetic (tests/test_telemetry.py).
+    governor:     a :class:`~repro.stability.model.GovernorConfig`
+                  (plcg only, DESIGN.md §18): every column converges
+                  only on a replacement whose freshly computed true
+                  residual meets its tol.  None (default) converges on
+                  the recursive residual.
     """
 
     def __init__(self, backend, s: int = 8, method: str = "plcg",
@@ -152,7 +157,7 @@ class SolverService:
                  registry: MetricsRegistry | None = None,
                  telemetry_cap: int = 0,
                  retry: RetryPolicy | None = None,
-                 fault_injector=None):
+                 fault_injector=None, governor=None):
         self.backend = backend
         self.s = int(s)
         self.method = method
@@ -163,6 +168,10 @@ class SolverService:
         self.block_size = block_size
         self.replace_every = int(replace_every)
         self.telemetry_cap = int(telemetry_cap)
+        self.governor = governor
+        if governor is not None and method != "plcg":
+            raise ConfigError("governor needs method='plcg' "
+                              f"(got {method!r})")
         if self.telemetry_cap and method != "plcg":
             raise ConfigError("telemetry_cap needs method='plcg' "
                               f"(got {method!r})")
@@ -245,6 +254,8 @@ class SolverService:
             if self.replace_every:
                 kw.update(replace_every=self.replace_every,
                           max_restarts=10 + self.maxit // self.replace_every)
+            if self.governor is not None:
+                kw.update(governor=self.governor)
         elif self.method == "pcg" and self.replace_every:
             kw.update(replace_every=self.replace_every)
         self._operators[key] = OperatorEntry(op=op, prec=prec,

@@ -101,6 +101,15 @@ class GovernorConfig:
                       discrepancy.
     ``kappa``         gap-model scale factor (operator-conditioning
                       fudge; 1.0 is the plain first-order model).
+    ``verify_only``   arm only the verification: the recursive residual
+                      reaching tol schedules a true-residual check, and
+                      only a check that meets tol converges.  The gap
+                      and patience arms never fire and the solve never
+                      stagnates, so replacements come from
+                      ``replace_every`` alone — the float32 policy,
+                      where the gap arm fires every few dozen updates
+                      and near-tol checks improve by less than
+                      ``improve_ratio``.
     """
 
     safety: float = 4.0
@@ -109,6 +118,7 @@ class GovernorConfig:
     demote_after: int = 3
     eps: float | None = None
     kappa: float = 1.0
+    verify_only: bool = False
 
     def resolved_patience(self, l: int) -> int:
         return int(self.patience) if self.patience > 0 else max(32, 8 * l)

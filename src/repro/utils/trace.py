@@ -59,6 +59,12 @@ _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(?:\([^=]*?\)|\S+)\s+([\w\-]+)\("
 )
 _OPNAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+# Instructions that compute nothing.  XLA hoists constants to the top of
+# the schedule and keeps the op_name of the scope that created them, so a
+# constant tagged ``plwin2/glred_wait`` sits before every start — never
+# an event.
+_INERT_OPS = ("constant", "parameter")
 _WINDOW_RE = re.compile(WINDOW_SCOPE + r"(\d+)(?:\D|$)")
 # Staged ring-ladder hops (DESIGN.md §14): ``lax.ppermute`` inside a
 # ``glred_hop{k}`` scope, k the global hop index 0..P-2.  Hop 0 is the
@@ -146,9 +152,11 @@ class OverlapReport:
         return "\n".join(lines)
 
 
-def _entry_instructions(hlo_text: str) -> list[tuple[str, str, str]]:
-    """(name, opcode, op_name-metadata) for the ENTRY computation, in
-    schedule (text) order."""
+def _entry_instructions(hlo_text: str
+                        ) -> list[tuple[str, str, str, list[str]]]:
+    """(name, opcode, op_name-metadata, operand names) for the ENTRY
+    computation, in schedule (text) order; inert instructions
+    (``_INERT_OPS``) are dropped."""
     out = []
     in_entry = False
     for line in hlo_text.splitlines():
@@ -159,10 +167,41 @@ def _entry_instructions(hlo_text: str) -> list[tuple[str, str, str]]:
             if line.startswith("}"):
                 break
             m = _INSTR_RE.match(line)
-            if not m:
+            if not m or m.group(2) in _INERT_OPS:
                 continue
             om = _OPNAME_RE.search(line)
-            out.append((m.group(1), m.group(2), om.group(1) if om else ""))
+            args = line[m.end():].split(")", 1)[0]
+            out.append((m.group(1), m.group(2), om.group(1) if om else "",
+                        _OPERAND_RE.findall(args)))
+    return out
+
+
+def _window(op_name: str) -> int | None:
+    wm = _WINDOW_RE.search(op_name)
+    return None if wm is None else int(wm.group(1))
+
+
+def _start_windows(instrs) -> list[tuple[int, int, str, str]]:
+    """(window, pos, opcode, name) for every tagged reduction start.
+
+    XLA's all-reduce combiner merges independent reductions — the
+    pipeline-fill iterations' dot blocks have no data dependence on each
+    other — into ONE tuple all-reduce that keeps only the first
+    constituent's op_name.  A collective start therefore counts once for
+    every window its operands come from (one handle per iteration, as
+    many iterations as the combined all-reduce carries)."""
+    op_names = {name: op_name for name, _o, op_name, _a in instrs}
+    out = []
+    for pos, (name, opcode, op_name, args) in enumerate(instrs):
+        k = _window(op_name)
+        if k is None or GLRED_START_TAG not in op_name:
+            continue
+        ks = [k]
+        if opcode in _COLLECTIVE_START_OPS and len(args) > 1:
+            ks = sorted({w for a in args
+                         if (w := _window(op_names.get(a, ""))) is not None}
+                        ) or ks
+        out.extend((w, pos, opcode, name) for w in ks)
     return out
 
 
@@ -180,11 +219,17 @@ def extract_events(hlo_text: str) -> list[ChainEvent]:
     waits: dict[int, ChainEvent] = {}
     halos: list[ChainEvent] = []
     hops: list[ChainEvent] = []
-    for pos, (name, opcode, op_name) in enumerate(instrs):
-        wm = _WINDOW_RE.search(op_name)
-        if wm is None:
+    for k, pos, opcode, name in _start_windows(instrs):
+        ev = ChainEvent("start", k, pos, opcode, name)
+        cur = starts.get(k)
+        is_coll = opcode in _COLLECTIVE_START_OPS
+        cur_coll = cur is not None and cur.opcode in _COLLECTIVE_START_OPS
+        if cur is None or (is_coll and not cur_coll):
+            starts[k] = ev
+    for pos, (name, opcode, op_name, _args) in enumerate(instrs):
+        k = _window(op_name)
+        if k is None:
             continue
-        k = int(wm.group(1))
         # Staged ladder hops are counted on their own axis: a hop that
         # executes inside the wait's scope (the steps the solver had not
         # advanced yet) carries BOTH glred_wait and glred_hop{j} — it is
@@ -195,13 +240,8 @@ def extract_events(hlo_text: str) -> list[ChainEvent]:
             hops.append(ChainEvent("hop", k, pos, opcode, name,
                                    hop=int(hm.group(1))))
         if GLRED_START_TAG in op_name:
-            ev = ChainEvent("start", k, pos, opcode, name)
-            cur = starts.get(k)
-            is_coll = opcode in _COLLECTIVE_START_OPS
-            cur_coll = cur is not None and cur.opcode in _COLLECTIVE_START_OPS
-            if cur is None or (is_coll and not cur_coll):
-                starts[k] = ev
-        elif GLRED_WAIT_TAG in op_name and not is_hop and k not in waits:
+            continue                     # starts: _start_windows above
+        if GLRED_WAIT_TAG in op_name and not is_hop and k not in waits:
             waits[k] = ChainEvent("wait", k, pos, opcode, name)
         elif HALO_TAG in op_name and opcode in _PERMUTE_OPS and not is_hop:
             # Every halo permute is an event (a window has one per
@@ -222,16 +262,10 @@ def reduction_starts_per_window(hlo_text: str) -> dict[int, int]:
     amortization claim of DESIGN.md §11, checked against compiled HLO in
     tests/test_distributed.py."""
     counts: dict[int, int] = {}
-    for _name, opcode, op_name in _entry_instructions(hlo_text):
-        if opcode not in _COLLECTIVE_START_OPS:
-            continue
-        if GLRED_START_TAG not in op_name:
-            continue
-        wm = _WINDOW_RE.search(op_name)
-        if wm is None:
-            continue
-        k = int(wm.group(1))
-        counts[k] = counts.get(k, 0) + 1
+    for k, _pos, opcode, _name in _start_windows(
+            _entry_instructions(hlo_text)):
+        if opcode in _COLLECTIVE_START_OPS:
+            counts[k] = counts.get(k, 0) + 1
     return counts
 
 
@@ -424,11 +458,7 @@ def batched_plcg_overlap_report(
 
         return jax.vmap(col, in_axes=1)(B_local)
 
-    try:
-        from jax.sharding import PartitionSpec as P
-        b_spec = P(getattr(backend, "axis", None), None) \
-            if hasattr(backend, "axis") else None
-    except ImportError:          # pragma: no cover
-        b_spec = None
+    from jax.sharding import PartitionSpec as P
+    b_spec = P(backend.axis, None) if hasattr(backend, "axis") else None
     hlo = backend.lower_hlo(harness, op, B, prec=prec, b_spec=b_spec)
     return analyze_overlap(hlo, l=l, window=window)

@@ -143,7 +143,10 @@ for l in (1, 2, 3):
     rep = plcg_overlap_report(be, op, bspec, l=l, window=l + 2,
                               sigmas=shifts_for_operator(op, l))
     assert rep.max_in_flight >= l, (l, rep.max_in_flight, str(rep))
-    assert rep.n_collectives >= l + 2, str(rep)
+    # one reduction per traced window; XLA's all-reduce combiner may
+    # merge the independent pipeline-fill reductions into one tuple
+    # all-reduce, so count handles per window, not instructions
+    assert rep.starts_per_window == {k: 1 for k in range(l + 2)}, str(rep)
 print("TRACER-OK")
 """)
     assert "TRACER-OK" in out
@@ -531,7 +534,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.attention import decode_attention_jnp, merge_decode_shards
 from repro.kernels import ops as kops
-from repro.parallel import shard_map_compat
 
 b, h, hkv, d, s = 2, 8, 4, 32, 512
 rng = np.random.default_rng(0)
@@ -544,10 +546,10 @@ def shard_fn(q, k, v):
     o, m, l = kops.decode_attention_stats(q, k, v, k.shape[1], block_s=64)
     return merge_decode_shards(o, m, l, "kv")
 
-fn = shard_map_compat(shard_fn, mesh=mesh,
-                      in_specs=(P(), P(None, "kv", None, None),
-                                P(None, "kv", None, None)),
-                      out_specs=P())
+fn = jax.shard_map(shard_fn, mesh=mesh,
+                   in_specs=(P(), P(None, "kv", None, None),
+                             P(None, "kv", None, None)),
+                   out_specs=P(), check_vma=False)
 merged = jax.jit(fn)(q, k, v).reshape(b, h, d)
 full = kops.decode_attention(q, k, v, kv_len=s, block_s=64)
 np.testing.assert_allclose(merged, np.asarray(full), rtol=3e-4, atol=3e-4)

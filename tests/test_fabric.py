@@ -17,6 +17,7 @@ Two layers, both tier-1 (no RUN_MULTIPROCESS gate):
   ``multiprocess`` job).
 """
 
+import os
 import sys
 import time
 import warnings
@@ -66,6 +67,34 @@ def test_launch_fabric_success_collects_all_ranks():
     assert len(res.outputs) == 3
     for k, out in enumerate(res.outputs):
         assert f"rank {k} on {res.coordinator} ok" in out
+
+
+def test_ranks_are_pinned_to_cpu_platform():
+    # A parent holding an accelerator must never spawn ranks that try to
+    # open it: every rank sees JAX_PLATFORMS=cpu whatever the parent has.
+    env = dict(os.environ, JAX_PLATFORMS="tpu")
+    res = launch_fabric(
+        _argv_script("import os; print('plat', os.environ['JAX_PLATFORMS'])"),
+        2, env=env, timeout_s=60, poll_s=0.05)
+    assert all("plat cpu" in out for out in res.outputs), res.outputs
+
+
+@pytest.mark.parametrize("platforms,slices,wire", [
+    (("tpu", "tpu"), (0, 0), "ici"),
+    (("tpu", "tpu"), (0, 1), "dcn"),
+    (("gpu", "gpu"), (0, 0), "nccl"),
+    (("cpu", "cpu"), (0, 0), "gloo"),
+])
+def test_hop_wire_names_the_transport(platforms, slices, wire):
+    from types import SimpleNamespace
+
+    from repro.parallel.backends.multiprocess import MultiprocessBackend
+
+    devs = [SimpleNamespace(platform=p, slice_index=s)
+            for p, s in zip(platforms, slices)]
+    fake = SimpleNamespace(n_processes=2,
+                           mesh=SimpleNamespace(devices=np.array(devs)))
+    assert MultiprocessBackend.hop_wire(fake) == wire
 
 
 def test_kill_one_process_raises_typed_error_not_hang():
@@ -168,12 +197,20 @@ def test_sigterm_handler_flushes_before_exit(tmp_path):
     # handler must run its flush callbacks (telemetry/timeline in prod —
     # a sentinel file here) before exiting with the distinct 143 status.
     sentinel = tmp_path / "flushed_rank0"
-    body = (f"import sys, time\n"
+    armed = tmp_path / "armed_rank0"
+    # Rank 1 dies only once rank 0 has armed its handler: a SIGTERM that
+    # lands while rank 0 is still importing would test nothing.
+    body = (f"import os, sys, time\n"
             f"from repro.parallel.fabric import install_sigterm_handler\n"
             f"if RANK == 1:\n"
+            f"    t = time.monotonic()\n"
+            f"    while not os.path.exists({str(armed)!r}) "
+            f"and time.monotonic() - t < 30:\n"
+            f"        time.sleep(0.02)\n"
             f"    sys.exit(7)\n"
             f"install_sigterm_handler(\n"
             f"    lambda: open({str(sentinel)!r}, 'w').write('flushed'))\n"
+            f"open({str(armed)!r}, 'w').close()\n"
             f"print('handler armed', flush=True)\n"
             f"time.sleep(120)\n")
     import os

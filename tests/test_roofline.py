@@ -17,7 +17,6 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.utils.hlo import count_collectives
 from repro.utils.roofline import cost_analysis_dict
-from repro.parallel import shard_map_compat
 
 mesh = jax.make_mesh((4,), ("x",))
 n = 512
@@ -33,9 +32,9 @@ assert abs(flops - 2 * n**3 / 4) / (2 * n**3 / 4) < 0.01, flops
 
 # psum of a replicated (n,n): partitioned all-reduce payload = full tensor
 comp2 = jax.jit(
-    lambda x: shard_map_compat(
+    lambda x: jax.shard_map(
         lambda v: jax.lax.psum(v, "x"), mesh=mesh,
-        in_specs=P("x", None), out_specs=P())(x),
+        in_specs=P("x", None), out_specs=P(), check_vma=False)(x),
     in_shardings=(bsh,), out_shardings=rep).lower(a).compile()
 c = count_collectives(comp2.as_text())
 ar = c.get("all-reduce", {"bytes": 0})

@@ -197,6 +197,31 @@ def test_service_end_to_end(method):
     assert st["latency_p99_s"] >= st["latency_p50_s"] > 0
 
 
+def test_service_governor_certifies_true_residual():
+    """With a governor every retired column converged on a replacement
+    whose fresh true residual met its tol — never on the recursion."""
+    from repro.stability import GovernorConfig
+
+    op = ops_mod.Stencil2D5(16, 16)
+    svc = SolverService(get_backend("local"), s=2, method="plcg", l=2,
+                        chunk_iters=25, maxit=800,
+                        governor=GovernorConfig(verify_only=True))
+    svc.register_operator("lap", op)
+    rng = np.random.default_rng(4)
+    sent = {svc.submit("lap", b, tol=1e-6): b
+            for b in rng.standard_normal((3, op.n))}
+    results = svc.drain()
+    for rid, b in sent.items():
+        r = results[rid]
+        assert r.converged
+        rel = np.linalg.norm(b - np.asarray(op.apply(jnp.asarray(r.x)))
+                             ) / np.linalg.norm(b)
+        assert rel < 1e-6, (rid, rel)
+    with pytest.raises(ConfigError, match="governor"):
+        SolverService(get_backend("local"), method="cg",
+                      governor=GovernorConfig())
+
+
 def test_typed_serve_errors():
     """Malformed traffic raises the typed ServeError hierarchy — one
     distinct exception per failure mode, all catchable as ServeError and

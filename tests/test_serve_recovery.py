@@ -131,6 +131,27 @@ def test_programming_bug_propagates_not_healed():
         svc.drain()
 
 
+def test_device_runtime_error_propagates_not_shed():
+    # A compile or device fault surfaces as a jax runtime error: it must
+    # reach the caller, never be healed into resubmit-then-shed.
+    import jax
+
+    op = Stencil2D5(12, 12)
+
+    def injector(tick, worker):
+        raise jax.errors.JaxRuntimeError("device fault")
+
+    svc = SolverService(get_backend("local"), s=4, method="plcg", l=2,
+                        chunk_iters=25, maxit=600, clock=VirtualClock(),
+                        retry=RetryPolicy(max_retries=3),
+                        fault_injector=injector)
+    svc.register_operator("lap", op)
+    svc.submit("lap", np.random.default_rng(0).standard_normal(op.n))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="device fault"):
+        svc.drain()
+    assert svc.worker_deaths == 0 and svc.shed == 0
+
+
 def test_reset_stats_clears_recovery_counters():
     svc, _, _, _ = _run(fault_tick=2)
     assert svc.resubmitted == 4 and svc.worker_deaths == 1

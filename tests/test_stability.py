@@ -148,6 +148,33 @@ def test_clean_governed_solve_truth_certified():
     assert _true_rel(op, b, res.x) < 1e-6
 
 
+def test_verify_only_governor_certifies_and_never_stagnates():
+    """``verify_only`` arms the verification alone: the same decisions
+    as a governor whose gap and patience arms cannot fire and which
+    never demotes (bitwise), convergence only from a true-residual
+    check, and at an unreachable tol it runs to maxit and never
+    declares stagnation."""
+    op, prec, ops, b = _problem()
+    kw = dict(l=2, maxit=600, max_restarts=60, replace_every=50)
+    never = 2**30
+    res = pipelined_cg.solve(ops, b, tol=1e-6,
+                             governor=GovernorConfig(verify_only=True), **kw)
+    ref = pipelined_cg.solve(ops, b, tol=1e-6, governor=GovernorConfig(
+        safety=0.0, patience=never, demote_after=never), **kw)
+    assert np.array_equal(np.asarray(res.res_history),
+                          np.asarray(ref.res_history))
+    assert np.array_equal(np.asarray(res.x), np.asarray(ref.x))
+    d = diagnose(res)
+    assert d["converged"] and not d["stagnated"]
+    assert _true_rel(op, b, res.x) < 1e-6
+
+    far = pipelined_cg.solve(ops, b, tol=1e-30,
+                             governor=GovernorConfig(verify_only=True), **kw)
+    d = diagnose(far)
+    assert not d["converged"] and not d["stagnated"]
+    assert d["iters"] == kw["maxit"]
+
+
 def test_governed_recovery_where_ungoverned_stagnates():
     """THE recovery demonstration (ISSUE acceptance): under a seeded
     ULP-scale reduction-payload fault at l=4, ungoverned ghysels p(l)-CG
