@@ -73,7 +73,9 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import GLRED_WAIT_TAG, SolveResult, SolverOps, dot1
+from repro.core.types import (CONTROL_TAG, GLRED_WAIT_TAG, INIT_TAG,
+                              RESTART_TAG, SCALAR_TAG, VECTOR_TAG,
+                              SolveResult, SolverOps, dot1)
 from repro.kernels.fused_iter import (SlabLayout, idx_layout, scal_layout,
                                       tel_layout)
 from repro.kernels.ref import fused_iter_unfused
@@ -348,286 +350,291 @@ def build(
         """
         c = st.cyc
         i = c.i
-        im = i - l                     # index of the Hessenberg column built
-        ge_l = i >= l
+        with jax.named_scope(SCALAR_TAG):
+            im = i - l                 # index of the Hessenberg column built
+            ge_l = i >= l
 
-        # ===== scalar phase: MPI_Wait arrival + K2 + K3 ===================
-        # O(l^2) scalar work on the G / Hessenberg windows — no vector
-        # traffic; produces the coefficients the vector phase consumes.
-        def late_scal(args):
-            G, gam, dlt = args
-            col = i - l + 1            # G column whose dots arrived
+            # ===== scalar phase: MPI_Wait arrival + K2 + K3 ===================
+            # O(l^2) scalar work on the G / Hessenberg windows — no vector
+            # traffic; produces the coefficients the vector phase consumes.
+            def late_scal(args):
+                G, gam, dlt = args
+                col = i - l + 1            # G column whose dots arrived
 
-            # ---- MPI_Wait(req(i-l)): consume the in-flight dot block -----
-            # The raw 2l+1 payload initiated l iterations ago is pulled out
-            # of the D ring and scattered into G column `col` only NOW —
-            # the consumption point the overlap tracer keys on (GLRED_WAIT
-            # scope; DESIGN.md §6).
-            with jax.named_scope(GLRED_WAIT_TAG):
-                # advanced=l-1: the solver ran one ladder step per
-                # iteration on this handle (ages 1..l-1, below); a staged
-                # substrate finishes any remaining steps here, monolithic
-                # ones ignore the count (DESIGN.md §14).
-                # .astype(dtype): staged substrates may accumulate the
-                # payload wider than the solver dtype (fp32 wire + fp64
-                # compensated wait, DESIGN.md §14) — normalize so the
-                # scalar recurrences keep the solver's dtype (no-op on
-                # monolithic substrates).
-                arrived = ops.wait(jax.lax.dynamic_index_in_dim(
-                    c.D, jnp.mod(im, l), axis=0, keepdims=False),
-                    advanced=l - 1).astype(dtype)
-                for t in range(2 * l + 1):         # rows im-2l+1 .. im+1
-                    row = im - 2 * l + 1 + t
-                    rv = row >= 0
-                    G = g_set(G, row, col,
-                              jnp.where(rv, arrived[t], g_get(G, row, col)))
+                # ---- MPI_Wait(req(i-l)): consume the in-flight dot block -----
+                # The raw 2l+1 payload initiated l iterations ago is pulled out
+                # of the D ring and scattered into G column `col` only NOW —
+                # the consumption point the overlap tracer keys on (GLRED_WAIT
+                # scope; DESIGN.md §6).
+                with jax.named_scope(GLRED_WAIT_TAG):
+                    # advanced=l-1: the solver ran one ladder step per
+                    # iteration on this handle (ages 1..l-1, below); a staged
+                    # substrate finishes any remaining steps here, monolithic
+                    # ones ignore the count (DESIGN.md §14).
+                    # .astype(dtype): staged substrates may accumulate the
+                    # payload wider than the solver dtype (fp32 wire + fp64
+                    # compensated wait, DESIGN.md §14) — normalize so the
+                    # scalar recurrences keep the solver's dtype (no-op on
+                    # monolithic substrates).
+                    arrived = ops.wait(jax.lax.dynamic_index_in_dim(
+                        c.D, jnp.mod(im, l), axis=0, keepdims=False),
+                        advanced=l - 1).astype(dtype)
+                    for t in range(2 * l + 1):         # rows im-2l+1 .. im+1
+                        row = im - 2 * l + 1 + t
+                        rv = row >= 0
+                        G = g_set(G, row, col,
+                                  jnp.where(rv, arrived[t], g_get(G, row, col)))
 
-            # ---- (K2) lines 9-10: correct column `col` -------------------
-            for t in range(l - 1):     # j = i-2l+2 .. i-l   (sequential in j)
-                j = i - 2 * l + 2 + t
-                jv = j >= 0
+                # ---- (K2) lines 9-10: correct column `col` -------------------
+                for t in range(l - 1):     # j = i-2l+2 .. i-l   (sequential in j)
+                    j = i - 2 * l + 2 + t
+                    jv = j >= 0
+                    ssum = jnp.zeros((), dtype)
+                    for s in range(l + 1 + t):          # k = i-3l+1+s  (<= j-1)
+                        k_ = i - 3 * l + 1 + s
+                        kv = (k_ >= 0) & jv
+                        ssum += g_get(G, k_, j, kv) * g_get(G, k_, col, kv)
+                    denom = jnp.where(jv, g_get(G, j, j, jv), jnp.ones((), dtype))
+                    denom = jnp.where(denom == 0, jnp.ones((), dtype), denom)
+                    val = (g_get(G, j, col, jv) - ssum) / denom
+                    G = g_set(G, j, col, jnp.where(jv, val, g_get(G, j, col, jv)))
+
                 ssum = jnp.zeros((), dtype)
-                for s in range(l + 1 + t):          # k = i-3l+1+s  (<= j-1)
+                for s in range(2 * l):                   # k = i-3l+1 .. i-l
                     k_ = i - 3 * l + 1 + s
-                    kv = (k_ >= 0) & jv
-                    ssum += g_get(G, k_, j, kv) * g_get(G, k_, col, kv)
-                denom = jnp.where(jv, g_get(G, j, j, jv), jnp.ones((), dtype))
-                denom = jnp.where(denom == 0, jnp.ones((), dtype), denom)
-                val = (g_get(G, j, col, jv) - ssum) / denom
-                G = g_set(G, j, col, jnp.where(jv, val, g_get(G, j, col, jv)))
+                    kv = k_ >= 0
+                    ssum += jnp.square(g_get(G, k_, col, kv))
+                arg = g_get(G, col, col) - ssum
+                breakdown = (arg <= 0) | ~jnp.isfinite(arg)       # line 11
+                sq = jnp.sqrt(jnp.where(breakdown, jnp.ones((), dtype), arg))
+                G = g_set(G, col, col, sq)
 
-            ssum = jnp.zeros((), dtype)
-            for s in range(2 * l):                   # k = i-3l+1 .. i-l
-                k_ = i - 3 * l + 1 + s
-                kv = k_ >= 0
-                ssum += jnp.square(g_get(G, k_, col, kv))
-            arg = g_get(G, col, col) - ssum
-            breakdown = (arg <= 0) | ~jnp.isfinite(arg)       # line 11
-            sq = jnp.sqrt(jnp.where(breakdown, jnp.ones((), dtype), arg))
-            G = g_set(G, col, col, sq)
+                # ---- (K3) lines 12-18: new Hessenberg column -----------------
+                g_mm = g_get(G, im, im)
+                g_mm_safe = jnp.where(g_mm == 0, jnp.ones((), dtype), g_mm)
+                g_mp = g_get(G, im, im + 1)
+                g_prev = g_get(G, im - 1, im, im >= 1)
+                d_prev = ring_get(dlt, im - 1, im >= 1)
+                sig_im = sig[jnp.clip(im, 0, l - 1)]
+                gam_early = (g_mp + sig_im * g_mm - g_prev * d_prev) / g_mm_safe
+                dlt_early = sq / g_mm_safe
+                gam_late = (
+                    g_mm * ring_get(gam, im - l) + g_mp * ring_get(dlt, im - l)
+                    - g_prev * d_prev
+                ) / g_mm_safe
+                dlt_late = sq * ring_get(dlt, im - l) / g_mm_safe
+                early = i < 2 * l
+                gam_new = jnp.where(early, gam_early, gam_late)
+                dlt_new = jnp.where(early, dlt_early, dlt_late)
+                gam = gam.at[jnp.mod(im, W)].set(gam_new)
+                dlt = dlt.at[jnp.mod(im, W)].set(dlt_new)
+                dlt_safe = jnp.where(dlt_new == 0, jnp.ones((), dtype), dlt_new)
+                # ``arrived`` rides along for the telemetry row (the consumed
+                # dot block is replicated scalar state) — unused and DCE'd
+                # when uninstrumented.
+                return (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown
 
-            # ---- (K3) lines 12-18: new Hessenberg column -----------------
-            g_mm = g_get(G, im, im)
-            g_mm_safe = jnp.where(g_mm == 0, jnp.ones((), dtype), g_mm)
-            g_mp = g_get(G, im, im + 1)
-            g_prev = g_get(G, im - 1, im, im >= 1)
-            d_prev = ring_get(dlt, im - 1, im >= 1)
-            sig_im = sig[jnp.clip(im, 0, l - 1)]
-            gam_early = (g_mp + sig_im * g_mm - g_prev * d_prev) / g_mm_safe
-            dlt_early = sq / g_mm_safe
-            gam_late = (
-                g_mm * ring_get(gam, im - l) + g_mp * ring_get(dlt, im - l)
-                - g_prev * d_prev
-            ) / g_mm_safe
-            dlt_late = sq * ring_get(dlt, im - l) / g_mm_safe
-            early = i < 2 * l
-            gam_new = jnp.where(early, gam_early, gam_late)
-            dlt_new = jnp.where(early, dlt_early, dlt_late)
-            gam = gam.at[jnp.mod(im, W)].set(gam_new)
-            dlt = dlt.at[jnp.mod(im, W)].set(dlt_new)
-            dlt_safe = jnp.where(dlt_new == 0, jnp.ones((), dtype), dlt_new)
-            # ``arrived`` rides along for the telemetry row (the consumed
-            # dot block is replicated scalar state) — unused and DCE'd
-            # when uninstrumented.
-            return (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown
+            def early_scal(args):
+                G, gam, dlt = args
+                return (G, gam, dlt, jnp.zeros((), dtype), jnp.ones((), dtype),
+                        jnp.zeros((2 * l + 1,), dtype)), jnp.asarray(False)
 
-        def early_scal(args):
-            G, gam, dlt = args
-            return (G, gam, dlt, jnp.zeros((), dtype), jnp.ones((), dtype),
-                    jnp.zeros((2 * l + 1,), dtype)), jnp.asarray(False)
-
-        scal_args = (c.G, c.gam, c.dlt)
-        if static_phase is None:
-            (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown = \
-                jax.lax.cond(ge_l, late_scal, early_scal, scal_args)
-        elif static_phase == "late":
-            (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown = \
-                late_scal(scal_args)
-        else:
-            (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown = \
-                early_scal(scal_args)
-
-        d2 = ring_get(dlt, im - 1, im >= 1)       # delta_{i-l-1}
-
-        # ---- (K6) scalar updates (lines 24-32, D-Lanczos factors) --------
-        gam0 = ring_get(gam, jnp.int32(0))
-        gam_im = ring_get(gam, im, ge_l)
-        d_prev = ring_get(dlt, im - 1, im >= 1)
-        is_first = i == l
-        eta0_safe = jnp.where(gam0 == 0, jnp.ones((), dtype), gam0)
-        do_upd = i >= l + 1
-        eta_prev_safe = jnp.where(c.eta_prev == 0, jnp.ones((), dtype),
-                                  c.eta_prev)
-        lam = d_prev / eta_prev_safe
-        eta_new = gam_im - lam * d_prev
-        eta_new_safe = jnp.where(eta_new == 0, jnp.ones((), dtype), eta_new)
-        zet_new = -lam * c.zet_prev
-
-        # ===== vector phase ===============================================
-        # Ring-row indices + coefficients for the one-pass calling
-        # convention shared by the unfused reference and the superkernel
-        # (repro.kernels.fused_iter; DESIGN.md §13).
-        sig_i = jnp.where(i < l, sig[jnp.clip(i, 0, l - 1)],
-                          jnp.zeros((), dtype))
-        idx = jnp.zeros((IX["size"],), jnp.int32)
-        for k in range(l):
-            idx = idx.at[IX["fill"] + k].set(zk_row(k, i + 1))
-            idx = idx.at[IX["rec_w"] + k].set(zk_row(k, i - l + k + 1))
-            idx = idx.at[IX["rec_a"] + k].set(zk_row(k + 1, i - l + k + 1))
-            idx = idx.at[IX["rec_b"] + k].set(zk_row(k, i - l + k))
-            idx = idx.at[IX["rec_c"] + k].set(zk_row(k, i - l + k - 1))
-            idx = idx.at[IX["f_fill"] + k].set(
-                ((i < l - 1) & (k >= i + 1)).astype(jnp.int32))
-            idx = idx.at[IX["mat_v"] + k].set(zk_row(0, i - 2 * l + 1 + k))
-        for t in range(l - 1):
-            idx = idx.at[IX["mat_z"] + t].set(zk_row(l, i - l + 2 + t))
-        idx = idx.at[IX["z_top"]].set(zk_row(l, i))
-        idx = idx.at[IX["zl_im1"]].set(zk_row(l, i - 1))
-        idx = idx.at[IX["z_w"]].set(zk_row(l, i + 1))
-        idx = idx.at[IX["u_i"]].set(u_row(i))
-        idx = idx.at[IX["u_im1"]].set(u_row(i - 1))
-        idx = idx.at[IX["u_w"]].set(u_row(i + 1))
-        idx = idx.at[IX["p_im"]].set(zk_row(0, im))
-        idx = idx.at[IX["f_late"]].set(ge_l.astype(jnp.int32))
-        idx = idx.at[IX["f_first"]].set(is_first.astype(jnp.int32))
-        idx = idx.at[IX["f_upd"]].set(do_upd.astype(jnp.int32))
-
-        scal = jnp.zeros((IS["size"],), dtype)
-        scal = scal.at[IS["sig_i"]].set(sig_i)
-        scal = scal.at[IS["gam_new"]].set(gam_new)
-        scal = scal.at[IS["d2"]].set(d2)
-        scal = scal.at[IS["dlt_safe"]].set(dlt_safe)
-        scal = scal.at[IS["zet_prev"]].set(c.zet_prev)
-        scal = scal.at[IS["d_prev"]].set(d_prev)
-        scal = scal.at[IS["eta_new_safe"]].set(eta_new_safe)
-        scal = scal.at[IS["eta0_safe"]].set(eta0_safe)
-        for k in range(l):
-            scal = scal.at[IS["c1"] + k].set(sig[k] - gam_new)
-
-        if fiter is not None:
-            # One HBM pass: SPMV + prec + fills + K4 + K6 + local dot
-            # partials in the superkernel, then ONE global reduction on
-            # the partials (K5's MPI_Iallreduce, same payload as ever).
-            S, partials = fiter(c.S, idx, scal)
-            dots = ops.start_partials(partials)
-        else:
-            S, mat, u_new = fused_iter_unfused(c.S, idx, scal, ops.apply_a,
-                                               ops.prec, layout)
-            # ---- (K5) line 23: initiate the dot block — ONE reduction ----
-            # The raw payload (rows i-2l+1 .. i+1 of G column i+1) is
-            # parked in the D ring; it is only consumed — and scattered
-            # into G — at iteration i+l (MPI_Wait above).  Between the two
-            # sites up to l reductions are simultaneously in flight.
-            dots = ops.start(mat, u_new)
-        D = c.D.at[jnp.mod(i, l)].set(dots)
-
-        # ---- staged-reduction progress: one ladder hop per iteration ----
-        # Every in-flight handle (pipeline age t = 1..l-1) advances by
-        # exactly one ladder step — the hop-per-iteration pipeline of
-        # DESIGN.md §14.  The step index is the handle's age minus one,
-        # STATIC under the while loop (only the ring slot is dynamic), so
-        # each ppermute's permutation is fixed at trace time.  Monolithic
-        # substrates make advance the identity and XLA folds the loop
-        # away; zero handles (early fill, post-restart) advance harmlessly
-        # (permuting zeros writes zeros).
-        for t in range(1, l):
-            slot = jnp.mod(i - t, l)
-            h = jax.lax.dynamic_index_in_dim(D, slot, axis=0,
-                                             keepdims=False)
-            D = jax.lax.dynamic_update_index_in_dim(
-                D, ops.advance(h, t - 1), slot, axis=0)
-
-        eta_prev = jnp.where(is_first, gam0,
-                             jnp.where(do_upd, eta_new, c.eta_prev))
-        zet_prev = jnp.where(is_first, c.norm0_cycle,
-                             jnp.where(do_upd, zet_new, c.zet_prev))
-
-        n_upd = jnp.where(do_upd, 1, 0).astype(jnp.int32)
-        upd = st.upd + n_upd
-        rnorm = jnp.abs(zet_new)
-        # On a breakdown iteration the freshly computed scalars are garbage
-        # (the restart discards them) — never record/converge on them.
-        ok = do_upd & ~breakdown
-        hist = jax.lax.cond(
-            ok,
-            lambda h: h.at[jnp.clip(upd, 0, H - 1)].set(rnorm),
-            lambda h: h,
-            st.hist,
-        )
-        # ---- stability governor: detection arms (DESIGN.md §18) ----------
-        # Pure replicated-scalar work on values the scalar phase already
-        # produced (the arrived dot block, the fresh Hessenberg entries) —
-        # zero extra reductions, statically absent when ungoverned.
-        gov = st.gov
-        gov_cols = {}
-        if governor is None:
-            converged = st.converged | (ok & (rnorm / st.norm0 < tol))
-        else:
-            M = gov_model
-            eps_c = jnp.asarray(governor.resolved_eps(dtype), dtype)
-            # G(col, col) is the arrived block's last entry — the squared
-            # scale of the newest basis vector.
-            basis = jnp.sqrt(jnp.abs(arrived[2 * l]))
-            # Grow by whichever is larger: the first-order eps model or
-            # the per-iteration drift rate MEASURED over the previous
-            # cycle (RATE; 0 until a restart measures one).  Under
-            # injected corruption far beyond eps the measured rate
-            # dominates and the gap arm fires within ~one cycle.
-            inc = M.gap_step(jnp.zeros((), dtype), gam_new, d2, dlt_safe,
-                             basis, eps_c, governor.kappa)
-            gap_acc = gov[M.GAP] + jnp.maximum(inc, gov[M.RATE])
-            gap = jnp.where(ge_l, gap_acc, gov[M.GAP])
-            rel = rnorm / st.norm0
-            improved = ok & (rel < governor.improve_ratio * gov[M.BEST])
-            best = jnp.where(improved, rel, gov[M.BEST])
-            best_upd = jnp.where(improved, upd.astype(dtype),
-                                 gov[M.BEST_UPD])
-            # Gap arm: the recursive residual is within ``safety`` of the
-            # (modeled + measured) gap — it can no longer be trusted.
-            # The recursion claiming convergence (rel < tol) is the same
-            # situation: both schedule a replacement, whose clean
-            # true-residual recompute either certifies convergence (the
-            # restart's lucky check) or re-seeds the gap with the
-            # measured discrepancy.  A governed solve therefore never
-            # sets ``converged`` from the recursion alone.
-            if governor.verify_only:
-                gap_due = ok & (rel < tol)
-                pat_due = jnp.asarray(False)
+            scal_args = (c.G, c.gam, c.dlt)
+            if static_phase is None:
+                (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown = \
+                    jax.lax.cond(ge_l, late_scal, early_scal, scal_args)
+            elif static_phase == "late":
+                (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown = \
+                    late_scal(scal_args)
             else:
-                gap_due = ok & ((governor.safety * gap >= rel) | (rel < tol))
-                pat_due = ok & (rel >= tol) & (
-                    upd.astype(dtype) - best_upd
-                    >= governor.resolved_patience(l))
-            code = jnp.where(
-                gap_due, jnp.asarray(M.ACTION_GAP_REPLACE, dtype),
-                jnp.where(pat_due,
-                          jnp.asarray(M.ACTION_PATIENCE_REPLACE, dtype),
-                          jnp.zeros((), dtype)))
-            due = jnp.where(gov[M.DUE] > 0, gov[M.DUE], code)
-            gov = (gov.at[M.GAP].set(gap).at[M.BEST].set(best)
-                      .at[M.BEST_UPD].set(best_upd).at[M.DUE].set(due))
-            gov_cols = {"gap": gap, "action": code}
-            converged = st.converged
+                (G, gam, dlt, gam_new, dlt_safe, arrived), breakdown = \
+                    early_scal(scal_args)
 
-        tel = tel_write(
-            st.tel, st.tot,
-            iter=st.tot, upd=upd,
-            rnorm=jnp.where(ok, rnorm, -jnp.ones((), dtype)),
-            age=jnp.minimum(i + 1, l),       # in-flight handles after park
-            breakdown=breakdown, dots=arrived, **gov_cols,
-        )
+            d2 = ring_get(dlt, im - 1, im >= 1)       # delta_{i-l-1}
 
-        cyc = _Cycle(
-            S=S, G=G, D=D, gam=gam, dlt=dlt,
-            eta_prev=eta_prev, zet_prev=zet_prev, i=i + 1,
-            norm0_cycle=c.norm0_cycle,
-        )
-        return _State(
-            cyc=cyc, tot=st.tot + 1, upd=upd, restarts=st.restarts,
-            converged=converged, breakdown=breakdown, hist=hist, norm0=st.norm0,
-            since_rr=st.since_rr + n_upd, tel=tel, gov=gov,
-        )
+            # ---- (K6) scalar updates (lines 24-32, D-Lanczos factors) --------
+            gam0 = ring_get(gam, jnp.int32(0))
+            gam_im = ring_get(gam, im, ge_l)
+            d_prev = ring_get(dlt, im - 1, im >= 1)
+            is_first = i == l
+            eta0_safe = jnp.where(gam0 == 0, jnp.ones((), dtype), gam0)
+            do_upd = i >= l + 1
+            eta_prev_safe = jnp.where(c.eta_prev == 0, jnp.ones((), dtype),
+                                      c.eta_prev)
+            lam = d_prev / eta_prev_safe
+            eta_new = gam_im - lam * d_prev
+            eta_new_safe = jnp.where(eta_new == 0, jnp.ones((), dtype), eta_new)
+            zet_new = -lam * c.zet_prev
 
+            # ===== vector phase ===============================================
+            # Ring-row indices + coefficients for the one-pass calling
+            # convention shared by the unfused reference and the superkernel
+            # (repro.kernels.fused_iter; DESIGN.md §13).
+            sig_i = jnp.where(i < l, sig[jnp.clip(i, 0, l - 1)],
+                              jnp.zeros((), dtype))
+            idx = jnp.zeros((IX["size"],), jnp.int32)
+            for k in range(l):
+                idx = idx.at[IX["fill"] + k].set(zk_row(k, i + 1))
+                idx = idx.at[IX["rec_w"] + k].set(zk_row(k, i - l + k + 1))
+                idx = idx.at[IX["rec_a"] + k].set(zk_row(k + 1, i - l + k + 1))
+                idx = idx.at[IX["rec_b"] + k].set(zk_row(k, i - l + k))
+                idx = idx.at[IX["rec_c"] + k].set(zk_row(k, i - l + k - 1))
+                idx = idx.at[IX["f_fill"] + k].set(
+                    ((i < l - 1) & (k >= i + 1)).astype(jnp.int32))
+                idx = idx.at[IX["mat_v"] + k].set(zk_row(0, i - 2 * l + 1 + k))
+            for t in range(l - 1):
+                idx = idx.at[IX["mat_z"] + t].set(zk_row(l, i - l + 2 + t))
+            idx = idx.at[IX["z_top"]].set(zk_row(l, i))
+            idx = idx.at[IX["zl_im1"]].set(zk_row(l, i - 1))
+            idx = idx.at[IX["z_w"]].set(zk_row(l, i + 1))
+            idx = idx.at[IX["u_i"]].set(u_row(i))
+            idx = idx.at[IX["u_im1"]].set(u_row(i - 1))
+            idx = idx.at[IX["u_w"]].set(u_row(i + 1))
+            idx = idx.at[IX["p_im"]].set(zk_row(0, im))
+            idx = idx.at[IX["f_late"]].set(ge_l.astype(jnp.int32))
+            idx = idx.at[IX["f_first"]].set(is_first.astype(jnp.int32))
+            idx = idx.at[IX["f_upd"]].set(do_upd.astype(jnp.int32))
+
+            scal = jnp.zeros((IS["size"],), dtype)
+            scal = scal.at[IS["sig_i"]].set(sig_i)
+            scal = scal.at[IS["gam_new"]].set(gam_new)
+            scal = scal.at[IS["d2"]].set(d2)
+            scal = scal.at[IS["dlt_safe"]].set(dlt_safe)
+            scal = scal.at[IS["zet_prev"]].set(c.zet_prev)
+            scal = scal.at[IS["d_prev"]].set(d_prev)
+            scal = scal.at[IS["eta_new_safe"]].set(eta_new_safe)
+            scal = scal.at[IS["eta0_safe"]].set(eta0_safe)
+            for k in range(l):
+                scal = scal.at[IS["c1"] + k].set(sig[k] - gam_new)
+
+        with jax.named_scope(VECTOR_TAG):
+            if fiter is not None:
+                # One HBM pass: SPMV + prec + fills + K4 + K6 + local dot
+                # partials in the superkernel, then ONE global reduction on
+                # the partials (K5's MPI_Iallreduce, same payload as ever).
+                S, partials = fiter(c.S, idx, scal)
+                dots = ops.start_partials(partials)
+            else:
+                S, mat, u_new = fused_iter_unfused(c.S, idx, scal, ops.apply_a,
+                                                   ops.prec, layout)
+                # ---- (K5) line 23: initiate the dot block — ONE reduction ----
+                # The raw payload (rows i-2l+1 .. i+1 of G column i+1) is
+                # parked in the D ring; it is only consumed — and scattered
+                # into G — at iteration i+l (MPI_Wait above).  Between the two
+                # sites up to l reductions are simultaneously in flight.
+                dots = ops.start(mat, u_new)
+            D = c.D.at[jnp.mod(i, l)].set(dots)
+
+            # ---- staged-reduction progress: one ladder hop per iteration ----
+            # Every in-flight handle (pipeline age t = 1..l-1) advances by
+            # exactly one ladder step — the hop-per-iteration pipeline of
+            # DESIGN.md §14.  The step index is the handle's age minus one,
+            # STATIC under the while loop (only the ring slot is dynamic), so
+            # each ppermute's permutation is fixed at trace time.  Monolithic
+            # substrates make advance the identity and XLA folds the loop
+            # away; zero handles (early fill, post-restart) advance harmlessly
+            # (permuting zeros writes zeros).
+            for t in range(1, l):
+                slot = jnp.mod(i - t, l)
+                h = jax.lax.dynamic_index_in_dim(D, slot, axis=0,
+                                                 keepdims=False)
+                D = jax.lax.dynamic_update_index_in_dim(
+                    D, ops.advance(h, t - 1), slot, axis=0)
+
+        with jax.named_scope(SCALAR_TAG):
+            eta_prev = jnp.where(is_first, gam0,
+                                 jnp.where(do_upd, eta_new, c.eta_prev))
+            zet_prev = jnp.where(is_first, c.norm0_cycle,
+                                 jnp.where(do_upd, zet_new, c.zet_prev))
+
+        with jax.named_scope(CONTROL_TAG):
+            n_upd = jnp.where(do_upd, 1, 0).astype(jnp.int32)
+            upd = st.upd + n_upd
+            rnorm = jnp.abs(zet_new)
+            # On a breakdown iteration the freshly computed scalars are garbage
+            # (the restart discards them) — never record/converge on them.
+            ok = do_upd & ~breakdown
+            hist = jax.lax.cond(
+                ok,
+                lambda h: h.at[jnp.clip(upd, 0, H - 1)].set(rnorm),
+                lambda h: h,
+                st.hist,
+            )
+            # ---- stability governor: detection arms (DESIGN.md §18) ----------
+            # Pure replicated-scalar work on values the scalar phase already
+            # produced (the arrived dot block, the fresh Hessenberg entries) —
+            # zero extra reductions, statically absent when ungoverned.
+            gov = st.gov
+            gov_cols = {}
+            if governor is None:
+                converged = st.converged | (ok & (rnorm / st.norm0 < tol))
+            else:
+                M = gov_model
+                eps_c = jnp.asarray(governor.resolved_eps(dtype), dtype)
+                # G(col, col) is the arrived block's last entry — the squared
+                # scale of the newest basis vector.
+                basis = jnp.sqrt(jnp.abs(arrived[2 * l]))
+                # Grow by whichever is larger: the first-order eps model or
+                # the per-iteration drift rate MEASURED over the previous
+                # cycle (RATE; 0 until a restart measures one).  Under
+                # injected corruption far beyond eps the measured rate
+                # dominates and the gap arm fires within ~one cycle.
+                inc = M.gap_step(jnp.zeros((), dtype), gam_new, d2, dlt_safe,
+                                 basis, eps_c, governor.kappa)
+                gap_acc = gov[M.GAP] + jnp.maximum(inc, gov[M.RATE])
+                gap = jnp.where(ge_l, gap_acc, gov[M.GAP])
+                rel = rnorm / st.norm0
+                improved = ok & (rel < governor.improve_ratio * gov[M.BEST])
+                best = jnp.where(improved, rel, gov[M.BEST])
+                best_upd = jnp.where(improved, upd.astype(dtype),
+                                     gov[M.BEST_UPD])
+                # Gap arm: the recursive residual is within ``safety`` of the
+                # (modeled + measured) gap — it can no longer be trusted.
+                # The recursion claiming convergence (rel < tol) is the same
+                # situation: both schedule a replacement, whose clean
+                # true-residual recompute either certifies convergence (the
+                # restart's lucky check) or re-seeds the gap with the
+                # measured discrepancy.  A governed solve therefore never
+                # sets ``converged`` from the recursion alone.
+                if governor.verify_only:
+                    gap_due = ok & (rel < tol)
+                    pat_due = jnp.asarray(False)
+                else:
+                    gap_due = ok & ((governor.safety * gap >= rel) | (rel < tol))
+                    pat_due = ok & (rel >= tol) & (
+                        upd.astype(dtype) - best_upd
+                        >= governor.resolved_patience(l))
+                code = jnp.where(
+                    gap_due, jnp.asarray(M.ACTION_GAP_REPLACE, dtype),
+                    jnp.where(pat_due,
+                              jnp.asarray(M.ACTION_PATIENCE_REPLACE, dtype),
+                              jnp.zeros((), dtype)))
+                due = jnp.where(gov[M.DUE] > 0, gov[M.DUE], code)
+                gov = (gov.at[M.GAP].set(gap).at[M.BEST].set(best)
+                          .at[M.BEST_UPD].set(best_upd).at[M.DUE].set(due))
+                gov_cols = {"gap": gap, "action": code}
+                converged = st.converged
+
+            tel = tel_write(
+                st.tel, st.tot,
+                iter=st.tot, upd=upd,
+                rnorm=jnp.where(ok, rnorm, -jnp.ones((), dtype)),
+                age=jnp.minimum(i + 1, l),       # in-flight handles after park
+                breakdown=breakdown, dots=arrived, **gov_cols,
+            )
+
+            cyc = _Cycle(
+                S=S, G=G, D=D, gam=gam, dlt=dlt,
+                eta_prev=eta_prev, zet_prev=zet_prev, i=i + 1,
+                norm0_cycle=c.norm0_cycle,
+            )
+            return _State(
+                cyc=cyc, tot=st.tot + 1, upd=upd, restarts=st.restarts,
+                converged=converged, breakdown=breakdown, hist=hist, norm0=st.norm0,
+                since_rr=st.since_rr + n_upd, tel=tel, gov=gov,
+            )
+
+    @jax.named_scope(RESTART_TAG)
     def do_restart(st: _State) -> _State:
         # Stagnation guard: a breakdown before the cycle's first solution
         # update (since_rr == 0) re-inits with a steepest-descent step so
@@ -712,6 +719,7 @@ def build(
             gov=gov,
         )
 
+    @jax.named_scope(CONTROL_TAG)
     def needs_interrupt(st: _State) -> jax.Array:
         due = st.breakdown
         if replace_every > 0:
@@ -726,9 +734,14 @@ def build(
             due = due | (st.gov[gov_model.DUE] > 0)
         return due
 
+    # The interrupt test and its conditional are control; the branch it
+    # takes nests its own phase scope inside, and the innermost phase
+    # scope names an instruction's phase.
+    @jax.named_scope(CONTROL_TAG)
     def body(st: _State) -> _State:
         return jax.lax.cond(needs_interrupt(st), do_restart, iteration, st)
 
+    @jax.named_scope(CONTROL_TAG)
     def cond(st: _State) -> jax.Array:
         keep = (
             (~st.converged)
@@ -743,6 +756,7 @@ def build(
             keep = keep & ~(st.gov[gov_model.STAGNATED] > 0)
         return keep
 
+    @jax.named_scope(INIT_TAG)
     def init(x0: jax.Array) -> _State:
         cyc0 = init_cycle(x0)
         norm0 = cyc0.norm0_cycle
@@ -756,6 +770,7 @@ def build(
                  else jnp.zeros((gov_model.N_SLOTS,), dtype)),
         )
 
+    @jax.named_scope(INIT_TAG)
     def finish(final: _State) -> SolveResult:
         return SolveResult(
             x=final.cyc.S[layout.x_row], iters=final.upd,
@@ -818,11 +833,10 @@ def solve(
         # Unrolled driver: expose an (unroll)-iteration window to XLA so the
         # latency-hiding scheduler can stagger the in-flight reductions
         # (DESIGN.md §2).  Semantics identical to unroll=1.
+        @jax.named_scope(CONTROL_TAG)
         def body_u(st: _State) -> _State:
-            for k in range(unroll):
-                with jax.named_scope(f"plu{k}"):
-                    st = jax.lax.cond(prog.cond(st), prog.body,
-                                      lambda s: s, st)
+            for _ in range(unroll):
+                st = jax.lax.cond(prog.cond(st), prog.body, lambda s: s, st)
             return st
 
         final = jax.lax.while_loop(prog.cond, body_u, st0)

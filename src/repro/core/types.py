@@ -63,6 +63,19 @@ HALO_TAG = "halo_xchg"
 # the hop/halo staggering invariant.
 REDUCE_TAG = "glred_hop"
 
+# Phase scopes of the p(l)-CG solve (``repro.core.pipelined_cg``).  The
+# innermost of them on an instruction's ``metadata op_name`` is its phase
+# (the loop body's interrupt conditional is control and nests the phase
+# of the branch it takes).  A profiler trace names the instructions it
+# ran, so joining trace event names to the compiled program's op_names
+# gives device time per phase on the trace's own clock (DESIGN.md §16).
+SCALAR_TAG = "plcg_scalar"      # arrival + K2/K3, K6 scalars, idx/scal packing
+VECTOR_TAG = "plcg_vector"      # SPMV, fills, K4, dot block, x/p, reduction start
+CONTROL_TAG = "plcg_control"    # history, governor arms, telemetry, loop tests
+RESTART_TAG = "plcg_restart"    # breakdown restart / residual replacement
+INIT_TAG = "plcg_init"          # first cycle set-up and the result
+PHASE_TAGS = (SCALAR_TAG, VECTOR_TAG, CONTROL_TAG, RESTART_TAG, INIT_TAG)
+
 
 def dot_block_rows(mat: jax.Array, vec: jax.Array) -> jax.Array:
     """The fused dot block (K, N) x (N,) -> (K,) as an elementwise

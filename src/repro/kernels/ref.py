@@ -69,70 +69,85 @@ def fused_iter_unfused(S, idx, scal, apply_a, prec, layout):
     def put(out, row, vec):
         return jax.lax.dynamic_update_index_in_dim(out, vec, row, axis=0)
 
+    # Sub-scopes of the vector phase, read only by the phase breakdown:
+    # spmv (A and M^{-1}), recur (the u / z / K4 recurrences and their
+    # stores), fill (pipeline-fill copies), dots (the dot block's
+    # operands) and xp (the x / p update).
     late = idx[IX["f_late"]] != 0
-    z_top = get(idx[IX["z_top"]])
-    u_i = get(idx[IX["u_i"]])
-    u_im1 = get(idx[IX["u_im1"]])
+    with jax.named_scope("spmv"):
+        z_top = get(idx[IX["z_top"]])
+    with jax.named_scope("recur"):
+        u_i = get(idx[IX["u_i"]])
+        u_im1 = get(idx[IX["u_im1"]])
 
-    az = apply_a(z_top)
-    u_new0 = az - scal[IS["sig_i"]] * u_i
-    u_new = jnp.where(
-        late,
-        (u_new0 - scal[IS["gam_new"]] * u_i
-         - scal[IS["d2"]] * u_im1) / scal[IS["dlt_safe"]],
-        u_new0)
+    with jax.named_scope("spmv"):
+        az = apply_a(z_top)
+    with jax.named_scope("recur"):
+        u_new0 = az - scal[IS["sig_i"]] * u_i
+        u_new = jnp.where(
+            late,
+            (u_new0 - scal[IS["gam_new"]] * u_i
+             - scal[IS["d2"]] * u_im1) / scal[IS["dlt_safe"]],
+            u_new0)
     if layout.recurrence == "stable":
         # Coupled recurrence (arXiv:1902.03100, DESIGN.md §18): recompute
         # the top basis vector as M^{-1} u_{i+1} from the recurred u
         # instead of recurring z independently.  Early iterations are
         # bitwise-unchanged (u_new == u_new0 there).
-        z_new = prec(u_new)
+        with jax.named_scope("spmv"):
+            z_new = prec(u_new)
         z_fill = z_new
     else:
-        z_new0 = prec(u_new0)
-        zl_im1 = get(idx[IX["zl_im1"]])
-        z_new = jnp.where(
-            late,
-            (z_new0 - scal[IS["gam_new"]] * z_top
-             - scal[IS["d2"]] * zl_im1) / scal[IS["dlt_safe"]],
-            z_new0)
+        with jax.named_scope("spmv"):
+            z_new0 = prec(u_new0)
+        with jax.named_scope("recur"):
+            zl_im1 = get(idx[IX["zl_im1"]])
+            z_new = jnp.where(
+                late,
+                (z_new0 - scal[IS["gam_new"]] * z_top
+                 - scal[IS["d2"]] * zl_im1) / scal[IS["dlt_safe"]],
+                z_new0)
         z_fill = z_new0
 
     out = S
-    for k in range(l):
-        row = idx[IX["fill"] + k]
-        fill_k = idx[IX["f_fill"] + k] != 0
-        out = put(out, row, jnp.where(fill_k, z_fill, get(row)))
+    with jax.named_scope("fill"):
+        for k in range(l):
+            row = idx[IX["fill"] + k]
+            fill_k = idx[IX["f_fill"] + k] != 0
+            out = put(out, row, jnp.where(fill_k, z_fill, get(row)))
 
     recs = []
-    for k in range(l):
-        zk1 = get(idx[IX["rec_a"] + k])
-        zm1 = get(idx[IX["rec_b"] + k])
-        zm2 = get(idx[IX["rec_c"] + k])
-        rec = (zk1 + scal[IS["c1"] + k] * zm1
-               - scal[IS["d2"]] * zm2) / scal[IS["dlt_safe"]]
-        val = jnp.where(late, rec, get(idx[IX["rec_w"] + k]))
-        recs.append(val)
-        out = put(out, idx[IX["rec_w"] + k], val)
+    with jax.named_scope("recur"):
+        for k in range(l):
+            zk1 = get(idx[IX["rec_a"] + k])
+            zm1 = get(idx[IX["rec_b"] + k])
+            zm2 = get(idx[IX["rec_c"] + k])
+            rec = (zk1 + scal[IS["c1"] + k] * zm1
+                   - scal[IS["d2"]] * zm2) / scal[IS["dlt_safe"]]
+            val = jnp.where(late, rec, get(idx[IX["rec_w"] + k]))
+            recs.append(val)
+            out = put(out, idx[IX["rec_w"] + k], val)
 
-    out = put(out, idx[IX["z_w"]], z_new)
-    out = put(out, idx[IX["u_w"]], u_new)
+        out = put(out, idx[IX["z_w"]], z_new)
+        out = put(out, idx[IX["u_w"]], u_new)
 
-    rows = [get(idx[IX["mat_v"] + t]) for t in range(l)] + [recs[0]]
-    rows += [get(idx[IX["mat_z"] + t]) for t in range(l - 1)] + [z_new]
-    mat = jnp.stack(rows)
+    with jax.named_scope("dots"):
+        rows = [get(idx[IX["mat_v"] + t]) for t in range(l)] + [recs[0]]
+        rows += [get(idx[IX["mat_z"] + t]) for t in range(l - 1)] + [z_new]
+        mat = jnp.stack(rows)
 
-    x_old = S[layout.x_row]
-    p_old = S[layout.p_row]
-    p_first = S[0] / scal[IS["eta0_safe"]]
-    p_new = (get(idx[IX["p_im"]])
-             - scal[IS["d_prev"]] * p_old) / scal[IS["eta_new_safe"]]
-    x_new = x_old + scal[IS["zet_prev"]] * p_old
-    do_upd = idx[IX["f_upd"]] != 0
-    is_first = idx[IX["f_first"]] != 0
-    out = out.at[layout.x_row].set(jnp.where(do_upd, x_new, x_old))
-    out = out.at[layout.p_row].set(
-        jnp.where(is_first, p_first, jnp.where(do_upd, p_new, p_old)))
+    with jax.named_scope("xp"):
+        x_old = S[layout.x_row]
+        p_old = S[layout.p_row]
+        p_first = S[0] / scal[IS["eta0_safe"]]
+        p_new = (get(idx[IX["p_im"]])
+                 - scal[IS["d_prev"]] * p_old) / scal[IS["eta_new_safe"]]
+        x_new = x_old + scal[IS["zet_prev"]] * p_old
+        do_upd = idx[IX["f_upd"]] != 0
+        is_first = idx[IX["f_first"]] != 0
+        out = out.at[layout.x_row].set(jnp.where(do_upd, x_new, x_old))
+        out = out.at[layout.p_row].set(
+            jnp.where(is_first, p_first, jnp.where(do_upd, p_new, p_old)))
     return out, mat, u_new
 
 

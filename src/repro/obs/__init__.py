@@ -16,6 +16,13 @@ Three pieces, one subsystem:
   (``repro.core.pipelined_cg`` / ``repro.core.types.TelemetrySlab`` /
   ``repro.kernels.fused_iter.tel_layout``) — this package only decodes
   and renders it.
+
+The solve's device-clock spans are its **phase scopes**
+(``repro.core.types.PHASE_TAGS``): ``jax.named_scope`` scopes that reach
+the compiled program as instruction ``op_name`` metadata.  A profiler
+trace names the instructions it ran, so joining the two gives device
+time per phase on the trace's own clock (the chip benchmark's
+``phase_reduce.py``); this package keeps no span clock of its own.
 """
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,

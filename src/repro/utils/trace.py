@@ -40,8 +40,7 @@ from repro.core.types import (GLRED_START_TAG, GLRED_WAIT_TAG, HALO_TAG,
                               REDUCE_TAG)
 from repro.utils.hlo import count_collectives
 
-# Window scope prefix used by the flat trace harness (and by the unrolled
-# while-loop driver, which uses "plu{k}").
+# Window scope prefix used by the flat trace harness.
 WINDOW_SCOPE = "plwin"
 
 # HLO opcodes that implement a started reduction on a distributed
