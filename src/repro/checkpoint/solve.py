@@ -245,14 +245,9 @@ def make_rel_fn(method: str, kw: dict) -> Callable:
     from repro.core.types import dot1
 
     if method == "plcg":
-        from repro.kernels.fused_iter import SlabLayout
-
-        layout = SlabLayout(l=int(kw["l"]),
-                            RB=max(int(kw["l"]) + 1, 3),
-                            recurrence=kw.get("recurrence", "ghysels"))
-
         def rel(ops, b, st):
-            x = st.cyc.S[layout.x_row]
+            layout = _slab_layout(kw, b.shape[0])
+            x = layout.from_row(st.cyc.S[layout.x_row])
             r = b - ops.apply_a(x)
             z = ops.prec(r)
             return jnp.sqrt(jnp.abs(dot1(ops, r, z))) / st.norm0
@@ -270,6 +265,33 @@ def make_rel_fn(method: str, kw: dict) -> Callable:
         return rel
     raise KeyError(f"method {method!r} does not support checkpointing "
                    "(no interrupt boundary)")
+
+
+def _slab_layout(kw: dict, n: int):
+    from repro.kernels.fused_iter import SlabLayout
+
+    return SlabLayout.for_solver(
+        int(kw["l"]), n, kw.get("recurrence", "ghysels"),
+        fused=bool(kw.get("fused_iteration", False)))
+
+
+def persist_codec(method: str, kw: dict, n: int):
+    """``(encode, decode)`` between a solver state and the form a
+    checkpoint persists, or None where they are the same.  A p(l)-CG
+    slab persists with flat rows (..., NV, n): its (R, 128) row storage
+    pads each shard's rows to whole tiles, so the stored form has to
+    drop the padding to restore onto another shard count (elastic
+    restore).  ``n`` is the vector length one substrate program sees
+    (a shard's under shard_map)."""
+    if method != "plcg":
+        return None
+    layout = _slab_layout(kw, n)
+
+    def with_slab(st, f):
+        return st._replace(cyc=st.cyc._replace(S=f(st.cyc.S)))
+
+    return (lambda st: with_slab(st, layout.rows_to_flat),
+            lambda st: with_slab(st, layout.rows_from_flat))
 
 
 def effective_kw(method: str, kw: dict, every: int) -> dict:
@@ -368,16 +390,21 @@ LAST_RESTORE: list[_Restored] = []
 
 
 def try_restore(template, cfg: CheckpointConfig, expect_meta: dict,
-                mask, rel_of_state: Callable[[Any], Any]):
+                mask, rel_of_state: Callable[[Any], Any], codec=None):
     """Load + certify the latest checkpoint in ``cfg.directory`` onto
     ``template``'s substrate; returns the template unchanged when no
-    checkpoint exists yet."""
+    checkpoint exists yet.  ``codec`` is the substrate's
+    ``(encode, decode)`` of the persisted form (``persist_codec``)."""
     path = latest_checkpoint(cfg.directory) if cfg.directory else None
     if path is None:
         return template
     payload, meta = load_checkpoint(path)
     check_meta(meta, expect_meta)
-    st = state_restore(template, payload, mask)
+    if codec is None:
+        st = state_restore(template, payload, mask)
+    else:
+        encode, decode = codec
+        st = decode(state_restore(encode(template), payload, mask))
     rel_now = float(np.asarray(rel_of_state(st)))
     rel_saved = float(meta["rel_true"])
     tol = cfg.certify_rtol * max(abs(rel_saved), np.finfo(np.float64).tiny)
@@ -394,8 +421,9 @@ def make_snapshot_fn(cfg: CheckpointConfig, meta_base: dict, mask,
                      method: str, rel_of_state, gather=None,
                      is_writer: bool = True):
     """Build the per-boundary snapshot callback (None when ``cfg`` has
-    no directory).  ``gather`` (distributed substrates) turns the
-    device state into a fully host-readable one first."""
+    no directory).  ``gather`` turns the device state into the
+    persisted form (``persist_codec``), fully host-readable on
+    distributed substrates."""
     if cfg.directory is None:
         return None
     os.makedirs(cfg.directory, exist_ok=True)
@@ -440,12 +468,16 @@ def checkpointed_solve(ops, b, method: str, x0, cfg: CheckpointConfig,
     rel_j = jax.jit(lambda s: rel(ops, b, s))
     meta_base = solver_meta(method, b.shape[0], b.dtype, kw, cfg.every)
     meta_base["treedef"] = state_treedef_str(st)
+    codec = persist_codec(method, kw, b.shape[0])
+    if codec is not None:
+        codec = tuple(jax.jit(f) for f in codec)
     if cfg.resume:
-        st = try_restore(st, cfg, meta_base, mask, rel_j)
+        st = try_restore(st, cfg, meta_base, mask, rel_j, codec=codec)
     seg = jax.jit(lambda s: jax.lax.while_loop(
         lambda t: prog.cond(t) & ~prog.needs_interrupt(t), prog.step, s))
     interrupt = jax.jit(prog.interrupt)
-    snapshot = make_snapshot_fn(cfg, meta_base, mask, method, rel_j)
+    snapshot = make_snapshot_fn(cfg, meta_base, mask, method, rel_j,
+                                gather=codec[0] if codec else None)
     st = run_segmented(st, cond=prog.cond, needs=prog.needs_interrupt,
                        seg=seg, interrupt=interrupt, method=method,
                        cfg=cfg, snapshot=snapshot)

@@ -85,29 +85,36 @@ BUILDERS: dict[str, Callable] = {
 
 
 def vector_mask(method: str, kw: dict | None = None):
-    """Pytree (matching the method's state) of bools: True for leaves
-    whose TRAILING axis is the domain-decomposed vector axis n.
+    """Pytree (matching the method's state) of ints: for a leaf that
+    holds vectors, the (negative) axis of the domain-decomposed vector
+    axis n; 0 for everything else.
 
     Distributed backends use this to build shard_map partition specs for
-    the slab state (vector leaves sharded on their last axis, everything
-    else — windows, scalars, histories — replicated).
+    the slab state (vector leaves sharded on that axis, everything else
+    — windows, scalars, histories — replicated).  Vectors are a leaf's
+    trailing axis, except in the p(l)-CG slab with (R, 128) tiled rows
+    (``kernels.fused_iter.SlabLayout``), which shards its R axis; the
+    solver kwargs ``kw`` say which row storage the solve uses.
     """
     if method == "cg":
         return classic_cg.CgState(
-            x=True, r=True, u=True, p=True,
-            gamma=False, it=False, conv=False, hist=False)
+            x=-1, r=-1, u=-1, p=-1,
+            gamma=0, it=0, conv=0, hist=0)
     if method == "pcg":
         return ghysels_pcg.PcgState(
-            S=True, gamma=False, alpha=False, it=False, conv=False,
-            hist=False, since_rr=False)
+            S=-1, gamma=0, alpha=0, it=0, conv=0,
+            hist=0, since_rr=0)
     if method == "plcg":
+        from repro.kernels.fused_iter import SlabLayout
+
+        fused = bool((kw or {}).get("fused_iteration", False))
+        rows = SlabLayout.for_solver(1, 1, fused=fused).row_shape
         cyc = pipelined_cg._Cycle(
-            S=True, G=False, D=False, gam=False, dlt=False,
-            eta_prev=False, zet_prev=False, i=False, norm0_cycle=False)
+            S=-len(rows), G=0, D=0, gam=0, dlt=0,
+            eta_prev=0, zet_prev=0, i=0, norm0_cycle=0)
         return pipelined_cg._State(
-            cyc=cyc, tot=False, upd=False, restarts=False, converged=False,
-            breakdown=False, hist=False, norm0=False, since_rr=False,
-            tel=False, gov=False)
+            cyc=cyc, tot=0, upd=0, restarts=0, converged=0,
+            breakdown=0, hist=0, norm0=0, since_rr=0, tel=0, gov=0)
     raise KeyError(method)
 
 

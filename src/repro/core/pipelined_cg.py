@@ -3,13 +3,16 @@
 Faithful JAX implementation with production storage: ALL vector state —
 the l+1 auxiliary bases Z^(0..l) in ring buffers (window max(l+1,3) per
 basis), the 3-deep u ring, the search direction p and the iterate x —
-lives in ONE contiguous structure-of-arrays slab ``S`` of shape (NV, N)
+lives in ONE contiguous structure-of-arrays slab ``S``
 (:class:`repro.kernels.fused_iter.SlabLayout`), total vector storage O(l)
 irrespective of iteration count (cf. the paper's 4l+1-vector budget,
-Table 1).  One array with one trailing N axis is what the fused-iteration
-superkernel tiles (DESIGN.md §13), what ``donate_argnums`` aliases across
+Table 1).  One array is what ``donate_argnums`` aliases across
 slab-program chunks, and what the G matrix / Hessenberg windows ride
-alongside as O(l^2) scalars.
+alongside as O(l^2) scalars.  On the default path the slab is
+(NV, R, 128), R = 8 * ceil(n / 1024): each row is whole (8, 128) TPU
+tiles, with a zero tail past n that the vector phase keeps zero, so rows
+read and store as dense vregs, in place.  The fused-iteration
+superkernel keeps the flat (NV, N) slab its kernel tiles (DESIGN.md §13).
 
 The communication structure per iteration i is exactly the paper's:
 
@@ -38,14 +41,15 @@ The vector phase has two interchangeable implementations sharing one
 index/coefficient calling convention:
 
   * **unfused** (default) — ``repro.kernels.ref.fused_iter_unfused``:
-    one jnp op per pass, dots via ``ops.start`` (the reference path);
+    one jnp op per pass on the tiled rows, the dot block reduced to
+    local partials (``ops.partials``) before any row is stored;
   * **fused** (``fused_iteration=True``) — the Pallas superkernel
     (``repro.kernels.fused_iter``): slab read once / written once per
     row tile, dot partials accumulated in VMEM, the single global
-    reduction issued on the partials via ``ops.start_partials``.  Both
-    paths evaluate identical expressions on identical operands, so
-    stencil-operator residual histories agree BITWISE
-    (tests/test_fused_iter.py; DESIGN.md §13).
+    reduction issued on the partials via ``ops.start_partials``, as on
+    the unfused path.  Both paths evaluate identical expressions on
+    identical operands, so stencil-operator residual histories agree
+    BITWISE (tests/test_fused_iter.py; DESIGN.md §13).
 
 Breakdown handling: square-root breakdown (line 10/11) triggers an explicit
 restart from the current iterate (§2.2), implemented as a state re-init
@@ -85,7 +89,8 @@ from repro.stability import model as gov_model
 class _Cycle(NamedTuple):
     """Per-restart-cycle state (re-initialized on breakdown)."""
 
-    S: jax.Array        # (NV, N) vector slab: ZK rings | U ring | p | x
+    S: jax.Array        # (NV, *row_shape) vector slab: ZK rings | U ring
+                        # | p | x (kernels.fused_iter.SlabLayout)
     G: jax.Array        # (W, W) sliding window of the basis-transform matrix
     D: jax.Array        # (l, *handle) in-flight dot blocks (reduction
                         # handles: the raw (2l+1,) payload on monolithic
@@ -221,12 +226,18 @@ def build(
     tot_max = maxit + (max_restarts + 1) * (l + 1)
     H = tot_max + 2
 
-    layout = SlabLayout(l=l, RB=RB, recurrence=recurrence)
+    layout = SlabLayout.for_solver(l, n, recurrence, fused=fused_iteration)
     NV = layout.nv
     IX = idx_layout(l)
     IS = scal_layout(l)
     TL = tel_layout(l)
     TK = TL["size"]
+
+    # The operator and preconditioner as maps of slab rows: a diagonal
+    # M^{-1} scales rows in place, anything else sees flat views.
+    apply_rows = layout.row_map(ops.apply_a)
+    prec_rows = (layout.row_map(ops.prec) if ops.prec_diag is None
+                 else layout.row_scale(ops.prec_diag))
 
     fiter = None
     if fused_iteration:
@@ -275,12 +286,16 @@ def build(
     # ------------------------------------------------------------- init ---
     def _make_cycle(x, u0_raw, r0_raw, eta0) -> _Cycle:
         safe = jnp.where(eta0 == 0, jnp.ones((), dtype), eta0)
-        v0 = r0_raw / safe
-        S = jnp.zeros((NV, n), dtype)
+        # The slab's one allocation: rows in ``layout.row_shape``, the
+        # tail past n zero (``to_row`` pads it; the vector phase keeps it
+        # zero).  z_0^(k) = v_0 for every basis k.
+        v0 = layout.to_row(r0_raw / safe)
+        rows = [jnp.zeros(layout.row_shape, dtype)] * NV
         for k in range(l + 1):
-            S = S.at[k * RB].set(v0)          # z_0^(k) = v_0 for all k
-        S = S.at[layout.u_off].set(u0_raw / safe)
-        S = S.at[layout.x_row].set(x)
+            rows[k * RB] = v0
+        rows[layout.u_off] = layout.to_row(u0_raw / safe)
+        rows[layout.x_row] = layout.to_row(x)
+        S = jnp.stack(rows)
         h0 = ops.handle_zeros((2 * l + 1,), dtype)
         return _Cycle(
             S=S, G=jnp.zeros((W, W), dtype).at[0, 0].set(1.0),
@@ -508,21 +523,21 @@ def build(
                 scal = scal.at[IS["c1"] + k].set(sig[k] - gam_new)
 
         with jax.named_scope(VECTOR_TAG):
+            # ---- (K5) line 23: initiate the dot block — ONE reduction ----
+            # The vector phase leaves this device's partials of the block;
+            # the raw payload (rows i-2l+1 .. i+1 of G column i+1) is
+            # parked in the D ring and only consumed — and scattered into
+            # G — at iteration i+l (MPI_Wait above).  Between the two sites
+            # up to l reductions are simultaneously in flight.
             if fiter is not None:
                 # One HBM pass: SPMV + prec + fills + K4 + K6 + local dot
-                # partials in the superkernel, then ONE global reduction on
-                # the partials (K5's MPI_Iallreduce, same payload as ever).
+                # partials in the superkernel.
                 S, partials = fiter(c.S, idx, scal)
-                dots = ops.start_partials(partials)
             else:
-                S, mat, u_new = fused_iter_unfused(c.S, idx, scal, ops.apply_a,
-                                                   ops.prec, layout)
-                # ---- (K5) line 23: initiate the dot block — ONE reduction ----
-                # The raw payload (rows i-2l+1 .. i+1 of G column i+1) is
-                # parked in the D ring; it is only consumed — and scattered
-                # into G — at iteration i+l (MPI_Wait above).  Between the two
-                # sites up to l reductions are simultaneously in flight.
-                dots = ops.start(mat, u_new)
+                S, partials = fused_iter_unfused(
+                    c.S, idx, scal, apply_rows, prec_rows, layout,
+                    ops.partials)
+            dots = ops.start_partials(partials)
             D = c.D.at[jnp.mod(i, l)].set(dots)
 
             # ---- staged-reduction progress: one ladder hop per iteration ----
@@ -639,7 +654,7 @@ def build(
         # Stagnation guard: a breakdown before the cycle's first solution
         # update (since_rr == 0) re-inits with a steepest-descent step so
         # the restart is guaranteed to make progress (see restart_cycle).
-        cyc = restart_cycle(st.cyc.S[layout.x_row],
+        cyc = restart_cycle(layout.from_row(st.cyc.S[layout.x_row]),
                             st.breakdown & (st.since_rr == 0))
         # A breakdown at a converged iterate is a "lucky breakdown": the
         # freshly computed residual M-norm at restart tells us directly.
@@ -773,7 +788,7 @@ def build(
     @jax.named_scope(INIT_TAG)
     def finish(final: _State) -> SolveResult:
         return SolveResult(
-            x=final.cyc.S[layout.x_row], iters=final.upd,
+            x=layout.from_row(final.cyc.S[layout.x_row]), iters=final.upd,
             restarts=final.restarts, converged=final.converged,
             res_history=final.hist, norm0=final.norm0,
             telemetry=final.tel if telemetry_cap else None,

@@ -78,21 +78,25 @@ PHASE_TAGS = (SCALAR_TAG, VECTOR_TAG, CONTROL_TAG, RESTART_TAG, INIT_TAG)
 
 
 def dot_block_rows(mat: jax.Array, vec: jax.Array) -> jax.Array:
-    """The fused dot block (K, N) x (N,) -> (K,) as an elementwise
-    product + trailing-axis reduction instead of ``mat @ vec``.
+    """The fused dot block (K, N) x (N,) -> (K,) as K elementwise
+    products, each reduced on its own, instead of ``mat @ vec``.
 
     Semantically identical; chosen because it is bitwise-REPRODUCIBLE
     across every execution shape this repo runs the block in: a vmapped
     ``dot_general`` (the batched multi-RHS slab) and the Pallas
     interpreter's per-grid-step dot (the fused superkernel off-TPU) hit
     different gemm kernels whose reduction order differs at the ULP
-    level, while a trailing-axis reduce lowers to the same per-row chain
-    everywhere.  Every substrate's ``dot_block`` and the superkernel's
-    in-VMEM partials use THIS expression, which is what makes
-    fused/unfused and batched/sequential residual histories bitwise
-    comparable (DESIGN.md §13; tests/test_fused_iter.py).
+    level, while a vector reduce lowers to the same chain everywhere (a
+    row of the superkernel's trailing-axis reduce included).  Every
+    substrate's ``dot_block`` and the superkernel's in-VMEM partials
+    reduce THIS way, which is what makes fused/unfused and
+    batched/sequential residual histories bitwise comparable
+    (DESIGN.md §13; tests/test_fused_iter.py).  Row by row, the block
+    never needs its K rows side by side: the p(l)-CG slab's rows are
+    flat views of (R, 128) tiles (``kernels.fused_iter.SlabLayout``),
+    and a (K, N) stack of them would be relaid on the TPU.
     """
-    return (mat * vec[None, :]).sum(axis=1)
+    return jnp.stack([(mat[k] * vec).sum() for k in range(mat.shape[0])])
 
 
 class SolveResult(NamedTuple):
@@ -199,6 +203,16 @@ class SolverOps:
     # ``start`` would (one psum on distributed substrates, a tagged
     # barrier locally) without re-reading any basis vector from HBM.
     combine_partials: Callable[[jax.Array], jax.Array] | None = None
+    # The local half of ``start``: ``start_partials(dot_block_partials(
+    # mat, vec))`` issues what ``start(mat, vec)`` does.  ``create``
+    # makes it ``dot_block_rows`` unless told otherwise (the eager
+    # ladder oracle splits the block into its virtual shards' partials);
+    # None, on a hand-rolled SolverOps, means ``dot_block``, the whole
+    # reduction at once.  The p(l)-CG vector phase reduces its dot block
+    # locally before it stores a row (``kernels.ref``), so the rows it
+    # reads need not outlive the stores.
+    dot_block_partials: Callable[[jax.Array, jax.Array], jax.Array] | None \
+        = None
     # Factory for the fused-iteration superkernel: called by
     # ``pipelined_cg.build(..., fused_iteration=True)`` with the solver's
     # :class:`repro.kernels.fused_iter.SlabLayout`; returns the
@@ -206,12 +220,25 @@ class SolverOps:
     # (new slab, local dot partials).  None means the substrate/operator
     # combination has no fused path (the solver raises).
     fused_iter_factory: Callable[..., Callable] | None = None
+    # ``prec`` as its diagonal where it is diagonal (1.0: the identity;
+    # a Jacobi inverse diagonal; None: not diagonal —
+    # ``linalg.preconditioners.inverse_diagonal``).  The p(l)-CG vector
+    # phase scales its tiled slab rows by it instead of passing them
+    # through ``prec`` as flat vectors (``SlabLayout.row_scale``).
+    prec_diag: jax.Array | float | None = None
 
     def start(self, mat: jax.Array, vec: jax.Array) -> jax.Array:
         """Initiate the fused dot block (the MPI_Iallreduce)."""
         if self.dot_block_start is None:
             return self.dot_block(mat, vec)
         return self.dot_block_start(mat, vec)
+
+    def partials(self, mat: jax.Array, vec: jax.Array) -> jax.Array:
+        """This device's partials of the dot block, for
+        :meth:`start_partials`."""
+        if self.dot_block_partials is None:
+            return self.dot_block(mat, vec)
+        return self.dot_block_partials(mat, vec)
 
     def advance(self, handle: jax.Array, step: int) -> jax.Array:
         """Run ladder step ``step`` of an in-flight reduction handle —
@@ -268,6 +295,9 @@ class SolverOps:
         dot_block_wait: Callable[..., jax.Array] | None = None,
         dot_block_advance: Callable[[jax.Array, int], jax.Array] | None = None,
         handle_zeros: Callable[..., jax.Array] | None = None,
+        prec_diag: jax.Array | float | None = None,
+        dot_block_partials: Callable[[jax.Array, jax.Array], jax.Array]
+        | None = None,
     ) -> "SolverOps":
         """Build SolverOps with tracer-tagged start/wait around dot_block.
 
@@ -310,18 +340,22 @@ class SolverOps:
             dot_block_handle_zeros=handle_zeros,
             combine_partials=combine_partials,
             fused_iter_factory=fused_iter_factory,
+            prec_diag=prec_diag,
+            dot_block_partials=dot_block_partials or dot_block_rows,
         )
 
     @staticmethod
     def local(op, prec=None) -> "SolverOps":
         """Single-device ops (tests, small problems)."""
         from repro.kernels.ops import fused_iteration_factory
+        from repro.linalg.preconditioners import inverse_diagonal
 
         pfun = (lambda v: v) if prec is None else (lambda v: prec.apply(v))
         return SolverOps.create(
             apply_a=lambda v: op.apply(v),
             prec=pfun,
             dot_block=dot_block_rows,
+            prec_diag=inverse_diagonal(prec),
             fused_iter_factory=fused_iteration_factory(op, prec),
         )
 

@@ -2,7 +2,7 @@
 pass over the basis slab (DESIGN.md §13).
 
 The per-iteration hot path of ``repro.core.pipelined_cg`` is, unfused,
-~a dozen separate memory-bound passes over the (NV, N) state slab: the
+~a dozen separate memory-bound passes over the state slab: the
 SPMV (K1), the pointwise preconditioner, the pipeline-fill copies, the
 2l+2 recurrence AXPYs of K4, the 2l+1 dot products of K5 and the x/p
 updates of K6 — each re-reading basis vectors the previous op just
@@ -64,15 +64,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 # ---------------------------------------------------------------- layout --
 
+LANES = 128            # a TPU vreg row; a tile is (_SUBLANES, LANES)
+_SUBLANES = 8           # a vreg / VMEM tile holds rows in groups of 8
+
+
 @dataclasses.dataclass(frozen=True)
 class SlabLayout:
-    """Row map of the contiguous p(l)-CG state slab (NV, N).
+    """Row map and row storage of the p(l)-CG state slab (NV, *row_shape).
 
     Rows 0 .. (l+1)*RB-1 hold the l+1 auxiliary-basis ring buffers
     (basis k, ring slot j -> row k*RB + j), followed by the 3-deep u
     ring, the search direction p and the iterate x.  One array, one
-    trailing N axis — exactly what a column-tiled kernel (and a
-    ``donate_argnums``'d jit boundary) wants.
+    leading row axis — what a ``donate_argnums``'d jit boundary wants.
+
+    Row storage (DESIGN.md §13).  ``tiled`` rows — the unfused path's —
+    are (R, 128) with R = 8 * ceil(n / 1024): every row is whole (8, 128)
+    TPU tiles, so a row is read and stored as dense vregs and its flat
+    view (R * 128,) is the same bytes (no relayout).  The R * 128 - n
+    tail entries are zero in every row and stay zero: the vector phase
+    is elementwise or a dot, and the operator and preconditioner see
+    only ``from_row``'s first n entries, their results padded back by
+    ``to_row``.  Flat rows (``tiled=False``, the (NV, N) slab) are the
+    superkernel's: its Pallas kernel tiles the trailing N axis, and a
+    relayout at its boundary on every iteration would cost what it saves.
+    The solver picks the storage from the path (:meth:`for_solver`); it
+    is not a setting.
 
     ``recurrence`` selects the top-basis update of the vector phase
     (DESIGN.md §18): ``"ghysels"`` (default) recurs z^(l) through its own
@@ -90,6 +106,67 @@ class SlabLayout:
     l: int
     RB: int
     recurrence: str = "ghysels"
+    n: int | None = None       # vector length; None: flat rows of any n
+    tiled: bool = False
+
+    @classmethod
+    def for_solver(cls, l: int, n: int, recurrence: str = "ghysels",
+                   fused: bool = False) -> "SlabLayout":
+        """The layout ``pipelined_cg.build`` uses: tiled rows unless the
+        vector phase runs through the superkernel."""
+        return cls(l=l, RB=max(l + 1, 3), recurrence=recurrence, n=n,
+                   tiled=not fused)
+
+    @property
+    def row_shape(self) -> tuple[int, ...]:
+        if not self.tiled:
+            return (self.n,)
+        tile = _SUBLANES * LANES
+        return (_SUBLANES * -(-self.n // tile), LANES)
+
+    def to_row(self, v: jax.Array) -> jax.Array:
+        """A flat n-vector as one slab row (zero tail when tiled)."""
+        if not self.tiled:
+            return v
+        rows, lanes = self.row_shape
+        return jnp.pad(v, (0, rows * lanes - self.n)).reshape(rows, lanes)
+
+    def from_row(self, r: jax.Array) -> jax.Array:
+        """A slab row as the flat n-vector it stores."""
+        if not self.tiled:
+            return r
+        return r.reshape(-1)[:self.n]
+
+    def row_map(self, f: Callable[[jax.Array], jax.Array]) -> Callable:
+        """A map of flat n-vectors (an operator, a block solve) as a map
+        of slab rows: it sees ``from_row``, its result goes back through
+        ``to_row``."""
+        if not self.tiled:
+            return f
+        return lambda r: self.to_row(f(self.from_row(r)))
+
+    def row_scale(self, diag: jax.Array | float) -> Callable:
+        """The diagonal map diag(``diag``) as a map of slab rows: an
+        elementwise product, the diagonal laid out as a row (1.0: the
+        identity)."""
+        if isinstance(diag, float):
+            return (lambda r: r) if diag == 1.0 else (lambda r: diag * r)
+        return lambda r: self.to_row(diag.astype(r.dtype)) * r
+
+    def rows_to_flat(self, S: jax.Array) -> jax.Array:
+        """A slab (..., NV, *row_shape) as flat rows (..., NV, n): the
+        substrate-independent form a checkpoint persists."""
+        if not self.tiled:
+            return S
+        return S.reshape(S.shape[:-2] + (-1,))[..., :self.n]
+
+    def rows_from_flat(self, F: jax.Array) -> jax.Array:
+        """Inverse of :meth:`rows_to_flat` (the tail comes back zero)."""
+        if not self.tiled:
+            return F
+        rows, lanes = self.row_shape
+        pad = [(0, 0)] * (F.ndim - 1) + [(0, rows * lanes - self.n)]
+        return jnp.pad(F, pad).reshape(F.shape[:-1] + (rows, lanes))
 
     @property
     def u_off(self) -> int:
@@ -286,7 +363,6 @@ def ell_spmv(cols: jax.Array, vals: jax.Array,
 # Mosaic's default scoped-VMEM limit for one kernel on TPU v5e: the
 # compiler refuses a kernel whose blocks and temporaries need more.
 VMEM_LIMIT_BYTES = 16 * 2**20
-_SUBLANES = 8           # a VMEM tile holds rows in groups of 8
 
 
 class FusedSlabTooLarge(ValueError):

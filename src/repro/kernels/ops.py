@@ -112,14 +112,12 @@ def fused_iteration_factory(op, prec=None):
     the per-iteration vector-phase callable consumed by
     ``pipelined_cg.build(..., fused_iteration=True)``.
     """
-    from repro.linalg.preconditioners import IdentityPrec, JacobiPrec
+    from repro.linalg.preconditioners import inverse_diagonal
 
-    if prec is None or isinstance(prec, IdentityPrec):
-        inv_diag = None
-    elif isinstance(prec, JacobiPrec):
-        inv_diag = prec.inv_diag
-    else:
-        return None
+    diag = inverse_diagonal(prec)
+    if diag is None:
+        return None                  # block solves are not pointwise
+    inv_diag = None if isinstance(diag, float) else diag
     spmv = _local_fused_spmv(op)
     if spmv is None:
         return None

@@ -43,14 +43,21 @@ def fused_axpy3_ref(zk1, zm1, zm2, c1, c2, scale):
     return out.astype(zk1.dtype)
 
 
-def fused_iter_unfused(S, idx, scal, apply_a, prec, layout):
+def fused_iter_unfused(S, idx, scal, apply_a, prec, layout, partials):
     """UNFUSED p(l)-CG vector phase — the memory-bound reference path the
     superkernel replaces (DESIGN.md §13): one separate jnp op per SPMV /
     preconditioner / fill copy / recurrence AXPY / solution update, each
-    re-reading the (NV, N) slab.  Returns ``(S', mat, u_new)`` with the
-    dot-block OPERANDS left unreduced so the caller issues the reduction
-    through its backend (``ops.start``); :func:`fused_iter_ref` closes
-    them into local partials for kernel-level comparison.
+    re-reading slab rows.  Returns ``(S', partials(mat, u_new))``: the
+    dot block reduced to this device's partials (``ops.partials``), for
+    the caller to issue through its backend (``ops.start_partials``),
+    as the superkernel's partials are.
+
+    Rows are ``layout.row_shape``: (R, 128) tiles on the solver's unfused
+    path, flat (N,) against the superkernel.  ``apply_a`` and ``prec``
+    map rows to rows (``SlabLayout.row_map`` / ``row_scale``); every
+    other op is elementwise on whole rows.  The dot block's operands are
+    the rows' flat views (``layout.from_row``): the flat (K, n) block
+    every substrate's ``dot_block`` reduces, whatever the storage.
 
     This function is also the production unfused path of
     ``repro.core.pipelined_cg`` — solver-level fused/unfused parity
@@ -63,16 +70,28 @@ def fused_iter_unfused(S, idx, scal, apply_a, prec, layout):
     IX = idx_layout(l)
     IS = scal_layout(l)
 
-    def get(row):
-        return jax.lax.dynamic_index_in_dim(S, row, 0, keepdims=False)
+    # Every store updates the slab in place.  For that, no op may read a
+    # version of the slab older than the one it stores into, or XLA
+    # copies the whole slab first.  So every value that reads the
+    # pre-iteration slab — the SPMV, the recurrences, the fills and the
+    # dot block — is made before one barrier that the slab passes
+    # through, and the x / p update reads the slab as stored so far.
+    # It reads no row stored this iteration except into a value a
+    # ``where`` discards (the ring windows are disjoint), so every value
+    # equals the superkernel's, which reads before its first write.
+    out = S
 
-    def put(out, row, vec):
-        return jax.lax.dynamic_update_index_in_dim(out, vec, row, axis=0)
+    def get(row):
+        return jax.lax.dynamic_index_in_dim(out, row, 0, keepdims=False)
+
+    def put(row, vec):
+        nonlocal out
+        out = jax.lax.dynamic_update_index_in_dim(out, vec, row, axis=0)
 
     # Sub-scopes of the vector phase, read only by the phase breakdown:
     # spmv (A and M^{-1}), recur (the u / z / K4 recurrences and their
-    # stores), fill (pipeline-fill copies), dots (the dot block's
-    # operands) and xp (the x / p update).
+    # stores), fill (pipeline-fill copies), dots (the dot block) and xp
+    # (the x / p update).
     late = idx[IX["f_late"]] != 0
     with jax.named_scope("spmv"):
         z_top = get(idx[IX["z_top"]])
@@ -109,12 +128,9 @@ def fused_iter_unfused(S, idx, scal, apply_a, prec, layout):
                 z_new0)
         z_fill = z_new0
 
-    out = S
     with jax.named_scope("fill"):
-        for k in range(l):
-            row = idx[IX["fill"] + k]
-            fill_k = idx[IX["f_fill"] + k] != 0
-            out = put(out, row, jnp.where(fill_k, z_fill, get(row)))
+        fills = [jnp.where(idx[IX["f_fill"] + k] != 0, z_fill,
+                           get(idx[IX["fill"] + k])) for k in range(l)]
 
     recs = []
     with jax.named_scope("recur"):
@@ -124,31 +140,41 @@ def fused_iter_unfused(S, idx, scal, apply_a, prec, layout):
             zm2 = get(idx[IX["rec_c"] + k])
             rec = (zk1 + scal[IS["c1"] + k] * zm1
                    - scal[IS["d2"]] * zm2) / scal[IS["dlt_safe"]]
-            val = jnp.where(late, rec, get(idx[IX["rec_w"] + k]))
-            recs.append(val)
-            out = put(out, idx[IX["rec_w"] + k], val)
+            recs.append(jnp.where(late, rec, get(idx[IX["rec_w"] + k])))
 
-        out = put(out, idx[IX["z_w"]], z_new)
-        out = put(out, idx[IX["u_w"]], u_new)
-
+    # Rows i-2l+1..i+1 of G column i+1: the ZK^(0) V-range (last entry
+    # freshly recurred), the ZK^(l) Z-range, and z_{i+1} itself.
     with jax.named_scope("dots"):
         rows = [get(idx[IX["mat_v"] + t]) for t in range(l)] + [recs[0]]
         rows += [get(idx[IX["mat_z"] + t]) for t in range(l - 1)] + [z_new]
-        mat = jnp.stack(rows)
+        dots = partials(jnp.stack([layout.from_row(r) for r in rows]),
+                        layout.from_row(u_new))
+
+    out, fills, recs, z_new, u_new, dots = jax.lax.optimization_barrier(
+        (out, fills, recs, z_new, u_new, dots))
+
+    with jax.named_scope("fill"):
+        for k in range(l):
+            put(idx[IX["fill"] + k], fills[k])
+    with jax.named_scope("recur"):
+        for k in range(l):
+            put(idx[IX["rec_w"] + k], recs[k])
+        put(idx[IX["z_w"]], z_new)
+        put(idx[IX["u_w"]], u_new)
 
     with jax.named_scope("xp"):
-        x_old = S[layout.x_row]
-        p_old = S[layout.p_row]
-        p_first = S[0] / scal[IS["eta0_safe"]]
-        p_new = (get(idx[IX["p_im"]])
-                 - scal[IS["d_prev"]] * p_old) / scal[IS["eta_new_safe"]]
-        x_new = x_old + scal[IS["zet_prev"]] * p_old
         do_upd = idx[IX["f_upd"]] != 0
         is_first = idx[IX["f_first"]] != 0
-        out = out.at[layout.x_row].set(jnp.where(do_upd, x_new, x_old))
-        out = out.at[layout.p_row].set(
-            jnp.where(is_first, p_first, jnp.where(do_upd, p_new, p_old)))
-    return out, mat, u_new
+        x_old = get(layout.x_row)
+        x_new = x_old + scal[IS["zet_prev"]] * get(layout.p_row)
+        put(layout.x_row, jnp.where(do_upd, x_new, x_old))
+        p_old = get(layout.p_row)
+        p_first = get(0) / scal[IS["eta0_safe"]]
+        p_new = (get(idx[IX["p_im"]])
+                 - scal[IS["d_prev"]] * p_old) / scal[IS["eta_new_safe"]]
+        put(layout.p_row, jnp.where(
+            is_first, p_first, jnp.where(do_upd, p_new, p_old)))
+    return out, dots
 
 
 def fused_iter_ref(S, idx, scal, apply_a, prec, layout):
@@ -159,8 +185,8 @@ def fused_iter_ref(S, idx, scal, apply_a, prec, layout):
     differently at the ULP level."""
     from repro.core.types import dot_block_rows
 
-    out, mat, u_new = fused_iter_unfused(S, idx, scal, apply_a, prec, layout)
-    return out, dot_block_rows(mat, u_new)
+    return fused_iter_unfused(S, idx, scal, apply_a, prec, layout,
+                              dot_block_rows)
 
 
 def decode_attention_ref(q, k, v, kv_len):

@@ -53,6 +53,19 @@ class JacobiPrec(Preconditioner):
         return self.inv_diag.astype(x.dtype) * x
 
 
+def inverse_diagonal(prec) -> jax.Array | float | None:
+    """M^{-1} as its diagonal where it is diagonal: 1.0 for no
+    preconditioner or the identity, ``inv_diag`` for Jacobi; None for a
+    block solve.  A diagonal M^{-1} acts on any vector storage by an
+    elementwise product (the superkernel's tile, the p(l)-CG slab's
+    (R, 128) rows)."""
+    if prec is None or isinstance(prec, IdentityPrec):
+        return 1.0
+    if isinstance(prec, JacobiPrec):
+        return prec.inv_diag
+    return None
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockJacobi(Preconditioner):
     """Block-Jacobi with precomputed dense block inverses.

@@ -31,11 +31,11 @@ from repro.core.types import SolverOps
 from repro.parallel.distributed import (
     _permutation_wrappers,
     batched_result_specs,
-    batched_state_specs,
     distributed_solve,
     distributed_solve_batched,
     make_solver_mesh,
     partitioned_solver_ops,
+    state_specs,
 )
 from repro.parallel.backends.base import ReductionBackend
 from repro.parallel.reduction import (StagedConfig, oracle_solver_ops,
@@ -121,7 +121,7 @@ class ShardMapBackend(ReductionBackend):
 
         Each piece is one shard_map-wrapped jit: the slab B (n, s) is
         domain-decomposed on n, the state's vector leaves shard their
-        trailing axis (``batched_state_specs``), and per-column scalars /
+        vector axis (``state_specs``), and per-column scalars /
         histories are replicated.  The state crosses the host boundary
         between chunks so the serve layer can retire and inject columns —
         with fixed shapes throughout, nothing ever retraces.
@@ -147,7 +147,8 @@ class ShardMapBackend(ReductionBackend):
         st_struct = jax.eval_shape(
             lambda BB: batched_mod.batched_init(ops_shape, BB, method, kw),
             jax.ShapeDtypeStruct((n, s), dtype))
-        st_specs = batched_state_specs(method, st_struct, axis)
+        st_specs = state_specs(st_struct, batched_mod.vector_mask(method, kw),
+                               axis)
         status_specs = SlabStatus(running=P(), converged=P(), iters=P())
 
         def staged(fn, in_specs, out_specs, donate=()):

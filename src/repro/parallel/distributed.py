@@ -56,7 +56,8 @@ from repro.linalg.operators import (
     Stencil3D7,
     Stencil3D27,
 )
-from repro.linalg.preconditioners import BlockJacobi, IdentityPrec, JacobiPrec
+from repro.linalg.preconditioners import (BlockJacobi, IdentityPrec,
+                                         JacobiPrec, inverse_diagonal)
 from repro.linalg.sparse import SparseOp
 
 
@@ -327,18 +328,24 @@ def _fused_spmv_local(op, loc, n_shards: int, axis: str):
     return None
 
 
+def _local_inverse_diagonal(prec, loc):
+    """``inverse_diagonal`` of the shard's preconditioner: its slice of
+    a Jacobi ``inv_diag`` (in ``loc``), 1.0 or None as globally."""
+    diag = inverse_diagonal(prec)
+    return diag if diag is None or isinstance(diag, float) \
+        else loc["inv_diag"]
+
+
 def _fused_factory_dist(op, prec, loc, n_shards: int, axis: str):
     """``SolverOps.fused_iter_factory`` for the shard_map substrate, or
     None for unsupported (operator, preconditioner) pairs."""
     from repro.kernels import fused_iter as fi
     from repro.kernels.ops import _interpret_default
 
-    if prec is None or isinstance(prec, IdentityPrec):
-        inv_diag = None
-    elif isinstance(prec, JacobiPrec):
-        inv_diag = loc["inv_diag"]
-    else:
+    diag = _local_inverse_diagonal(prec, loc)
+    if diag is None:
         return None                  # block solves are not pointwise
+    inv_diag = None if isinstance(diag, float) else diag
     spmv = _fused_spmv_local(op, loc, n_shards, axis)
     if spmv is None:
         return None
@@ -399,6 +406,7 @@ def partitioned_solver_ops(op, prec, n_shards: int, axis: str = "shards",
             staged_kw = reduction_mod.staged_ops_pieces(cfg)
         return SolverOps.create(
             apply_a=apply_a, prec=prec_fn, dot_block=dot_block,
+            prec_diag=_local_inverse_diagonal(prec, loc["prec"]),
             fused_iter_factory=_fused_factory_dist(
                 op, prec, {**loc["op"], **loc["prec"]}, n_shards, axis),
             **staged_kw,
@@ -433,23 +441,37 @@ def _permutation_wrappers(perm):
 _METHODS = METHODS
 
 
-def batched_state_specs(method: str, state_shapes, axis: str):
-    """Partition specs for a batched slab state under ``shard_map``.
+def state_specs(state_shapes, vec_axes, axis: str):
+    """Partition specs for a (batched or single) solver state under
+    ``shard_map``.
 
-    ``state_shapes`` is the ``jax.eval_shape`` pytree of the batched
-    state (leading s-axis).  Vector-valued leaves (trailing axis = the
-    domain-decomposed n, per ``repro.core.batched.vector_mask``) shard
-    their LAST axis over ``axis``; windows, scalars and histories are
-    replicated (they are derived from psum'd dot blocks, hence identical
-    on every shard)."""
-    mask = batched_mod.vector_mask(method)
-
-    def spec(sh, is_vec):
-        if not is_vec:
+    ``state_shapes`` is the ``jax.eval_shape`` pytree of the state and
+    ``vec_axes`` its ``repro.core.batched.vector_mask``: vector-valued
+    leaves shard their domain-decomposed axis over ``axis``; windows,
+    scalars and histories are replicated (they are derived from psum'd
+    dot blocks, hence identical on every shard)."""
+    def spec(sh, vec_axis):
+        if not vec_axis:
             return P()
-        return P(*([None] * (sh.ndim - 1) + [axis]))
+        dims = [None] * sh.ndim
+        dims[sh.ndim + vec_axis] = axis
+        return P(*dims)
 
-    return jax.tree.map(spec, state_shapes, mask)
+    return jax.tree.map(spec, state_shapes, vec_axes)
+
+
+def result_specs(axis: str, kw: dict) -> SolveResult:
+    """Out-specs of a single-RHS SolveResult solved with kwargs ``kw``:
+    x is domain-decomposed; everything else replicated.  The telemetry
+    ring is replicated when instrumented (every recorded scalar is
+    post-psum state) and absent otherwise, mirroring
+    SolveResult.telemetry; the governor vector follows the same
+    contract (DESIGN.md §16/§18)."""
+    return SolveResult(
+        x=P(axis), iters=P(), restarts=P(), converged=P(),
+        res_history=P(), norm0=P(),
+        telemetry=P() if kw.get("telemetry_cap", 0) else None,
+        governor=P() if kw.get("governor") is not None else None)
 
 
 def batched_result_specs(axis: str, telemetry: bool = False,
@@ -555,9 +577,9 @@ def distributed_checkpointed_solve(
     x0_p = jnp.zeros_like(b_p) if x0 is None else pre(x0.astype(b_p.dtype))
 
     # Host-side program: cond / needs_interrupt touch only replicated
-    # scalar leaves and finish only slices the state, so a shape-oracle
-    # ops (never executed through a collective) is sufficient.  The
-    # staged oracle shares the staged mesh's handle-ring structure
+    # scalar leaves, so a shape-oracle ops (never executed through a
+    # collective) is sufficient; its state's leaf ranks give the specs.
+    # The staged oracle shares the staged mesh's handle-ring structure
     # (DESIGN.md §14), so the eval_shape'd state tree matches.
     ops_shape = SolverOps.local(op, prec) if reduction is None else \
         oracle_solver_ops(op, prec, dataclasses.replace(
@@ -568,10 +590,15 @@ def distributed_checkpointed_solve(
             f"method {method!r} exposes no interrupt boundary")
     st_shapes = jax.eval_shape(prog_host.init,
                                jax.ShapeDtypeStruct(b_p.shape, b_p.dtype))
-    vec = batched_mod.vector_mask(method)
-    st_specs = jax.tree.map(
-        lambda sh, v: P(*([None] * (sh.ndim - 1) + [axis])) if v else P(),
-        st_shapes, vec)
+    vec = batched_mod.vector_mask(method, kw)
+    st_specs = state_specs(st_shapes, vec, axis)
+    # The persisted form (``checkpoint.solve.persist_codec``) keeps
+    # vectors flat: each shard's n_l entries on the trailing axis (the
+    # eval_shape'd form serves for its leaves' ranks only).
+    codec = ckpt_mod.solve.persist_codec(method, kw, b.shape[0] // n_shards)
+    flat_shapes = jax.eval_shape(codec[0], st_shapes) if codec else st_shapes
+    flat_vec = jax.tree.map(lambda v: -1 if v else 0, vec)
+    flat_specs = state_specs(flat_shapes, flat_vec, axis)
     arr_specs = jax.tree.map(lambda _: P(axis), arrays)
 
     def _prog(bl, loc):
@@ -593,15 +620,22 @@ def distributed_checkpointed_solve(
     def _rel(bl, st, loc):
         return rel_fn(build(loc), bl, st)
 
+    def _encode(st):
+        return codec[0](st) if codec else st
+
+    def _decode(st):
+        return codec[1](st) if codec else st
+
     def _gather(st):
         # Vector leaves -> fully replicated global arrays (tiled
-        # all_gather on the trailing n axis); everything else is already
-        # replicated at a drained-ring boundary (post-psum scalars) —
-        # EXCEPT the in-flight D ring, which the checkpoint excludes.
+        # all_gather of the persisted form's trailing n axis); everything
+        # else is already replicated at a drained-ring boundary
+        # (post-psum scalars) — EXCEPT the in-flight D ring, which the
+        # checkpoint excludes.
         return jax.tree.map(
             lambda v, is_vec: lax.all_gather(v, axis, axis=v.ndim - 1,
                                              tiled=True) if is_vec else v,
-            st, vec)
+            _encode(st), flat_vec)
 
     sm = partial(jax.shard_map, mesh=mesh, check_vma=False)
     init_j = jax.jit(sm(_init, in_specs=(P(axis), P(axis), arr_specs),
@@ -614,6 +648,13 @@ def distributed_checkpointed_solve(
                        out_specs=P()))
     gather_j = jax.jit(sm(_gather, in_specs=(st_specs,),
                           out_specs=jax.tree.map(lambda _: P(), st_shapes)))
+    fin_j = jax.jit(sm(lambda bl, st, loc: _prog(bl, loc).finish(st),
+                       in_specs=(P(axis), st_specs, arr_specs),
+                       out_specs=result_specs(axis, kw)))
+    encode_j = jax.jit(sm(_encode, in_specs=(st_specs,),
+                          out_specs=flat_specs))
+    decode_j = jax.jit(sm(_decode, in_specs=(flat_specs,),
+                          out_specs=st_specs))
 
     st = init_j(b_p, x0_p, arrays)
     if pieces:
@@ -622,7 +663,8 @@ def distributed_checkpointed_solve(
         # e.g. "the seg piece keeps one pipelined reduction start per
         # iteration"), plus the initial state to lower them against.
         return {"init": init_j, "seg": seg_j, "interrupt": int_j,
-                "rel": rel_j, "gather": gather_j, "state": st,
+                "rel": rel_j, "gather": gather_j, "encode": encode_j,
+                "decode": decode_j, "state": st,
                 "b_p": b_p, "arrays": arrays, "prog_host": prog_host}
     mask = ckpt_mod.solve.exclude_mask(method, st)
     meta_base = ckpt_mod.solve.solver_meta(method, b.shape[0], b.dtype, kw,
@@ -630,7 +672,8 @@ def distributed_checkpointed_solve(
     meta_base["treedef"] = ckpt_mod.solve.state_treedef_str(st)
     rel_of = lambda s: rel_j(b_p, s, arrays)
     if cfg.resume:
-        st = ckpt_mod.solve.try_restore(st, cfg, meta_base, mask, rel_of)
+        st = ckpt_mod.solve.try_restore(st, cfg, meta_base, mask, rel_of,
+                                        codec=(encode_j, decode_j))
     snapshot = ckpt_mod.solve.make_snapshot_fn(
         cfg, meta_base, mask, method, rel_of, gather=gather_j,
         is_writer=jax.process_index() == 0)
@@ -639,7 +682,7 @@ def distributed_checkpointed_solve(
         seg=lambda s: seg_j(b_p, s, arrays), method=method,
         interrupt=lambda s: int_j(b_p, s, arrays), cfg=cfg,
         snapshot=snapshot)
-    return post(prog_host.finish(st))
+    return post(fin_j(b_p, st, arrays))
 
 
 def distributed_solve(
@@ -670,19 +713,10 @@ def distributed_solve(
         ops = build(local_arrays)
         return _METHODS[method](ops, b_local, kwargs)
 
-    out_specs = SolveResult(
-        x=P(axis), iters=P(), restarts=P(), converged=P(),
-        res_history=P(), norm0=P(),
-        # Replicated when instrumented (every recorded scalar is post-psum
-        # state), absent otherwise — mirrors SolveResult.telemetry; the
-        # governor vector follows the same contract (DESIGN.md §18).
-        telemetry=P() if kwargs.get("telemetry_cap", 0) else None,
-        governor=P() if kwargs.get("governor") is not None else None,
-    )
     arr_specs = jax.tree.map(lambda _: P(axis), arrays)
     inner = jax.shard_map(
-        run, mesh=mesh, in_specs=(P(axis), arr_specs), out_specs=out_specs,
-        check_vma=False)
+        run, mesh=mesh, in_specs=(P(axis), arr_specs),
+        out_specs=result_specs(axis, kwargs), check_vma=False)
 
     def fn(b, arrays):
         return post(inner(pre(b), arrays))

@@ -303,10 +303,14 @@ def oracle_start(mat: jax.Array, vec: jax.Array,
 
 
 def oracle_partials(partials: jax.Array, cfg: StagedConfig) -> jax.Array:
-    """Oracle ``combine_partials``: a single device has ONE partial —
-    file it as the full gather buffer (slice splitting happens inside
+    """Oracle ``combine_partials``.  The unfused path's partials come
+    from :func:`oracle_start`, already one per virtual shard: they are
+    the gather buffer.  The superkernel's single device has ONE partial
+    — file it as the full gather buffer (slice splitting happens inside
     the superkernel's own accumulation, which the oracle cannot redo),
-    zero elsewhere.  Used by the fused path on the local substrate."""
+    zero elsewhere."""
+    if partials.ndim == 2:                   # (P, K) from oracle_start
+        return partials
     wire = cfg.wire_dtype(partials.dtype)
     buf = jnp.zeros((cfg.n_shards,) + partials.shape, wire)
     return buf.at[0].set(partials.astype(wire))
@@ -339,6 +343,7 @@ def oracle_ops_pieces(cfg: StagedConfig, solver_dtype=None) -> dict:
 
     return dict(dot_block_start=start, dot_block_advance=advance,
                 dot_block_wait=wait, handle_zeros=handle_zeros,
+                dot_block_partials=start,
                 combine_partials=lambda p_: oracle_partials(p_, cfg))
 
 
@@ -413,12 +418,14 @@ def oracle_solver_ops(op, prec, cfg: StagedConfig):
     programs."""
     from repro.core.types import SolverOps
     from repro.kernels.ops import fused_iteration_factory
+    from repro.linalg.preconditioners import inverse_diagonal
 
     pfun = (lambda v: v) if prec is None else (lambda v: prec.apply(v))
     return SolverOps.create(
         apply_a=lambda v: op.apply(v),
         prec=pfun,
         dot_block=dot_block_rows,
+        prec_diag=inverse_diagonal(prec),
         fused_iter_factory=fused_iteration_factory(op, prec),
         **oracle_ops_pieces(cfg),
     )

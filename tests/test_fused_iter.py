@@ -225,8 +225,8 @@ def test_iteration_bytes_grow_with_depth():
 
 def _slab_copy_count(prog, B, st):
     txt = prog.chunk.lower(B, st).compile().as_text()
-    s, nv, n = st.cyc.S.shape
-    shape = f"f64[{s},{nv},{n}]"
+    # (s, NV, N) fused, (s, NV, R, 128) tiled rows (SlabLayout)
+    shape = f"f64[{','.join(map(str, st.cyc.S.shape))}]"
     alias = "input_output_alias" in txt.splitlines()[0]
     copies = sum(line.count(" copy(") for line in txt.splitlines()
                  if shape in line)
@@ -236,7 +236,7 @@ def _slab_copy_count(prog, B, st):
 @pytest.mark.parametrize("fused", [False, True])
 def test_slab_program_donation(fused):
     """The slab program's chunk donates its state: the jit boundary
-    aliases the (s, NV, N) slab (input_output_alias in the compiled
+    aliases the (s, NV, *row) slab (input_output_alias in the compiled
     module), the while loop carries it with NO per-iteration copy (the
     slab-shaped copy count is INVARIANT to chunk length — a per-
     iteration copy would scale it), and the donated buffer is actually

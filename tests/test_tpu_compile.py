@@ -9,14 +9,22 @@ stencil); at ``laplace2d`` size it must refuse with its typed error
 before compiling.  The one-chip p(l)-CG program at 2048^2
 float32 compiles, and so does the 4-device ``shard_map`` program on the
 described 2x2 mesh, with the monolithic psum and with the staged ring
-ladder — the staged one carrying no dot-block all-reduce.
+ladder — the staged one carrying no dot-block all-reduce.  The chip
+benchmark's own programs (``benchmarks/chip/configs``, 2000^2, one chip
+and four) keep the p(l)-CG slab in whole (8, 128) tiles: no vector
+lives in a one-sublane tiling, and every row store updates the slab in
+place.
 
 The topology is described inside a fixture: only one process may load
 the TPU library at a time, so nothing here touches it at import.
 """
 
 import importlib.util
+import json
+import math
 import os
+import re
+import sys
 from pathlib import Path
 
 import jax
@@ -192,3 +200,71 @@ def test_four_chip_plcg_program_compiles(topo, reduction):
     else:
         assert n_ar >= 1, "psum program lost its dot-block all-reduce"
     assert np.prod(mesh.devices.shape) == 4
+
+
+# --------------------------------------------- the slab's tile layout --
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _bench_program(name):
+    """(op, solver kwargs) of a chip benchmark configuration, built by the
+    benchmark's own harness (its backend is built by the test)."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_harness", ROOT / "benchmarks" / "chip" / "harness.py")
+    harness = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = harness        # its dataclasses look it up
+    spec.loader.exec_module(harness)
+    config = json.loads((ROOT / "benchmarks" / "chip" / "configs"
+                         / f"{name}.json").read_text())
+    config["backend"] = {"name": "local"}
+    op, _backend, kw = harness.build_program(config, ROOT)
+    return op, kw
+
+
+def _one_sublane_vectors(hlo: str, n: int) -> int:
+    """Values of at least a vector's length in a one-sublane T(1,128)
+    tiling: every vreg that holds one uses 1 of its 8 sublanes."""
+    count = 0
+    for dims in re.findall(r"= f32\[([0-9,]+)\]\{[^}]*T\(1,128\)", hlo):
+        if math.prod(int(d) for d in dims.split(",")) >= n:
+            count += 1
+    return count
+
+
+def _slab_ops(hlo: str, slab: str) -> list[str]:
+    """Opcodes of the instructions whose value is the whole slab."""
+    return re.findall(re.escape(slab) + r"\{[^}]*\} ([a-z-]+)\(", hlo)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_benchmark_slab_is_tile_aligned(topo, chips):
+    """The 2000^2 p(2)-CG programs of ``laplace2d`` (one chip) and
+    ``laplace2d-4chip`` (the psum program, four): the slab is
+    (14, R, 128) with R = 8 * ceil(n / 1024) for the n one chip holds;
+    no vector-length value is tiled T(1,128) (a flat (14, N) slab gives
+    106 on one chip and 102 on four); and the slab is only ever updated
+    in place — dynamic-update-slices, never a copy of it or a scatter
+    into it."""
+    op, kw = _bench_program("laplace2d" if chips == 1 else "laplace2d-4chip")
+    spec = jax.ShapeDtypeStruct((op.n,), jnp.float32)
+    with jax.enable_x64(False):
+        if chips == 1:
+            sh = SingleDeviceSharding(topo.devices[0])
+            solver = get_backend("local").make_solver(op, "plcg", **kw)
+            compiled = solver.lower(_spec((op.n,), sh)).compile()
+        else:
+            mesh = make_solver_mesh(4, devices=topo.devices)
+            shard = NamedSharding(mesh, P(mesh.axis_names[0]))
+            fn, arrays = distributed_solve(mesh, op, spec, method="plcg",
+                                           jit=False, **kw)
+            compiled = jax.jit(fn).lower(_spec((op.n,), shard),
+                                         arrays).compile()
+    hlo = compiled.as_text()
+    n = op.n // chips
+    slab = f"f32[14,{8 * -(-n // 1024)},128]"
+    ops = _slab_ops(hlo, slab)
+    assert ops, f"no {slab} slab in the program"
+    assert _one_sublane_vectors(hlo, n) == 0
+    assert "dynamic-update-slice" in ops
+    assert "copy" not in ops and "scatter" not in ops, sorted(set(ops))
