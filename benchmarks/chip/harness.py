@@ -3,28 +3,30 @@
 Everything particular to a configuration, a traffic mix or a metric is a
 file of its own, found by the name ``BENCHMARK.json`` gives it:
 
-* ``configs/<config>.json``    the deployment as it is run: problem sizes,
-                               solver policy, backend, precision, and the
-                               name of its plain reference;
+* ``configs/<config>.json``    the deployment as it is run: problem sizes
+                               and preconditioner, solver policy, backend,
+                               precision, and the name of its plain
+                               reference;
 * ``references/<name>.py``     that plain reference (``apply_np``,
                                ``apply_jnp``, ``true_rel_residual``);
 * ``traffic/<mix>.json``       the mix's parameters, read by ``traffic.py``;
 * ``metrics/<metric>.py``      a reader ``read(run) -> float | None`` of one
                                metric from a :class:`RunRecord`.
 
-A run builds the operator and the solver through the program's public
-entry points, makes the right-hand-side pool on the device from the seed,
-compiles the cell's one solver program ahead of time (from the persistent
-compilation cache after the first run), then solves back to back for the
-window.  A solve started inside the window is finished, so the window
-holds whole solves.  Once it has closed, a sample of the window's
-solutions drawn from the seed (all of them, where the window holds no more
-than ``CHECKED``) is checked on the host against the plain reference in
-float64.
+A run builds the operator, the preconditioner and the solver through the
+program's public entry points, makes the right-hand-side pool on the
+device from the seed, compiles the cell's one solver program ahead of
+time (from the persistent compilation cache after the first run), then
+solves back to back for the window.  A solve started inside the window
+is finished, so the window holds whole solves.  Once it has closed, a
+sample of the window's solutions drawn from the seed (all of them, where
+the window holds no more than ``CHECKED``) is checked on the host against
+the plain reference in float64.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import importlib.util
@@ -133,6 +135,9 @@ class RunRecord:
     window_s: float
     solves: list[Solve]
     trace: object | None = None        # trace_reduce.TraceSummary
+    phases: object | None = None       # phase_reduce.PhaseSummary
+    config: dict = dataclasses.field(default_factory=dict)  # as run
+    traced_from: int = 0               # the first solve the trace holds
 
     @property
     def certified(self) -> int:
@@ -142,6 +147,15 @@ class RunRecord:
     def updates(self) -> int:
         return sum(s.iters for s in self.solves)
 
+    @property
+    def traced(self) -> list[Solve]:
+        """The solves of the traced part of the window."""
+        return self.solves[self.traced_from:]
+
+    @property
+    def traced_updates(self) -> int:
+        return sum(s.iters for s in self.traced)
+
 
 # ---------------------------------------------------------------- program --
 
@@ -149,13 +163,38 @@ def program_path(root: Path) -> Path:
     return root / "src"
 
 
-def build_program(config: dict, root: Path):
-    """(op, backend, solver kwargs) through the program's entry points."""
+def build_preconditioner(op, problem: dict):
+    """The preconditioner ``problem["prec"]`` names, built on ``op`` through
+    the program's preconditioner classes: None for ``none``, point Jacobi
+    for ``jacobi``, and for ``blockjacobi`` dense inverses of the diagonal
+    blocks of ``problem["block_size"]`` unknowns."""
+    from repro.linalg.preconditioners import BlockJacobi, JacobiPrec
+
+    kind = problem.get("prec", "none")
+    if kind == "none":
+        return None
+    if kind == "jacobi":
+        return JacobiPrec.from_operator(op)
+    if kind == "blockjacobi":
+        size = int(problem.get("block_size", 0))
+        if size <= 0 or op.n % size:
+            raise BenchError(f"prec 'blockjacobi' needs a block_size that "
+                             f"divides n = {op.n}, got {size}")
+        return BlockJacobi.from_operator(op, size)
+    raise BenchError(f"unknown preconditioner {kind!r}")
+
+
+def build_program(config: dict, root: Path, setup: dict | None = None):
+    """(op, backend, solver kwargs) through the program's entry points; the
+    kwargs hold the preconditioner (``prec``) and the Chebyshev shifts
+    for the operator it preconditions.  ``setup`` gets the seconds the
+    operator and the preconditioner took (``operator_s``, ``prec_s``)."""
     src = program_path(root)
     if not (src / "repro").is_dir():
         raise BenchError(f"the program is not in this checkout ({src})")
     if str(src) not in sys.path:
         sys.path.insert(0, str(src))
+    import jax
     import jax.numpy as jnp
 
     from repro.configs.laplace2d import CGProblem
@@ -164,18 +203,24 @@ def build_program(config: dict, root: Path):
     from repro.parallel import get_backend
     from repro.stability import GovernorConfig
 
-    prob = config["problem"]
-    sol = config["solver"]
-    if prob.get("prec", "none") != "none":
-        raise BenchError(f"{config['name']}: the harness builds no "
-                         f"preconditioner yet (prec={prob['prec']!r})")
+    setup = {} if setup is None else setup
+    t = time.perf_counter()
+    # block_size sizes the preconditioner, not the operator.
+    prob = {k: v for k, v in config["problem"].items() if k != "block_size"}
     op = build_operator(CGProblem(name=config["name"], **prob))
-    dtype = jnp.dtype(config["dtype"])
     be_cfg = dict(config["backend"])
     backend = get_backend(be_cfg.pop("name"), **be_cfg)
-    kw = dict(l=sol["l"], tol=sol["tol"], maxit=sol["maxit"],
-              sigmas=shifts_for_operator(op, sol["l"], dtype=dtype),
-              replace_every=sol["replace_every"],
+    setup["operator_s"] = time.perf_counter() - t
+
+    t = time.perf_counter()
+    sol = config["solver"]
+    dtype = jnp.dtype(config["dtype"])
+    prec = build_preconditioner(op, config["problem"])
+    sigmas = jax.block_until_ready(
+        shifts_for_operator(op, sol["l"], dtype=dtype, prec=prec))
+    setup["prec_s"] = time.perf_counter() - t
+    kw = dict(prec=prec, l=sol["l"], tol=sol["tol"], maxit=sol["maxit"],
+              sigmas=sigmas, replace_every=sol["replace_every"],
               max_restarts=sol["max_restarts"],
               governor=GovernorConfig(**sol["governor"]))
     return op, backend, kw
@@ -235,10 +280,8 @@ def run_cell(*, workload: str, cell: dict, config: dict, mix: dict,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     setup["init_s"] = time.perf_counter() - t_start
 
-    t = time.perf_counter()
-    op, backend, kw = build_program(config, root)
+    op, backend, kw = build_program(config, root, setup)
     reference = load_module(reference_path(config["reference"], bench_dir))
-    setup["operator_s"] = time.perf_counter() - t
 
     import jax.numpy as jnp
     from traffic import rhs_pool
@@ -265,9 +308,13 @@ def run_cell(*, workload: str, cell: dict, config: dict, mix: dict,
 
     tracer = None
     if trace:
-        from trace_reduce import Tracer
+        from trace_reduce import TRACED_SPAN, Tracer
         tracer = Tracer(tempfile.mkdtemp(prefix="chipbench_trace_"))
-        tracer.start()
+    # A traced run profiles the window's last 1/chips: the trace, and the
+    # minutes the profiler takes to write it, grow with the updates it
+    # holds on every chip.
+    trace_from_s = seconds - seconds / chips
+    traced_from = None
     outs, solves = [], []
     # No collection inside the window: a full one takes tens of
     # milliseconds here, which would land inside a solve's timing.
@@ -275,9 +322,17 @@ def run_cell(*, workload: str, cell: dict, config: dict, mix: dict,
     gc.disable()
     t0 = time.perf_counter()
     try:
-        with jax.profiler.TraceAnnotation("bench.window"):
+        with contextlib.ExitStack() as traced:
             k = 0
-            while time.perf_counter() - t0 < seconds:
+            # A traced run holds at least one traced solve.
+            while time.perf_counter() - t0 < seconds or \
+                    (tracer is not None and traced_from is None):
+                if tracer is not None and traced_from is None \
+                        and time.perf_counter() - t0 >= trace_from_s:
+                    tracer.start()
+                    traced.enter_context(
+                        jax.profiler.TraceAnnotation(TRACED_SPAN))
+                    traced_from = len(solves)
                 ts = time.perf_counter()
                 j = k % len(pool)
                 with jax.profiler.TraceAnnotation("bench.solve"):
@@ -292,9 +347,19 @@ def run_cell(*, workload: str, cell: dict, config: dict, mix: dict,
         window_s = time.perf_counter() - t0
     finally:
         gc.enable()
-    summary = tracer.stop(chips) if tracer is not None else None
+    summary = phases = None
     if tracer is not None:
+        t = time.perf_counter()
+        summary, phases = tracer.stop(chips, program.as_text())
         shutil.rmtree(tracer.logdir, ignore_errors=True)
+        phase_sum = sum(phases.phase_s.values())
+        log("[trace] " + " ".join(f"{k}={v:.3f}"
+                                  for k, v in tracer.seconds.items())
+            + f" total_s={time.perf_counter() - t:.3f}"
+            + f" traced_solves={len(solves) - traced_from}"
+            + " phase_sum_over_busy="
+            + (f"{phase_sum / summary.busy_s:.6f}" if summary.busy_s
+               else "none"))
     used = devices[:chips]
     peak_mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in used]
     peak_mem = max((p for p in peak_mem if p is not None), default=None)
@@ -321,7 +386,8 @@ def run_cell(*, workload: str, cell: dict, config: dict, mix: dict,
     rec = RunRecord(chips=chips, n=op.n, l=int(kw["l"]),
                     itemsize=dtype.itemsize, tol=tol, peaks=peaks,
                     setup_s=setup_s, window_s=window_s, solves=solves,
-                    trace=summary)
+                    trace=summary, phases=phases, config=config,
+                    traced_from=traced_from or 0)
     values = {}
     for m in metrics:
         v = load_module(metric_path(m["name"], bench_dir)).read(rec)
@@ -346,7 +412,7 @@ def run_cell(*, workload: str, cell: dict, config: dict, mix: dict,
     if summary is not None:
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
-        result["breakdown"] = summary.breakdown()
+        result["breakdown"] = {**summary.breakdown(), **phases.breakdown()}
     result["checks"] = checks
     return result
 

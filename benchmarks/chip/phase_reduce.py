@@ -32,20 +32,19 @@ Every figure is a mean over the chips used; ``loop_gaps`` is chip 0's.
 
 from __future__ import annotations
 
-import bisect
 import dataclasses
 import re
 
-from trace_reduce import (DEVICE_PLANE, WINDOW_SPAN, device_ops, gaps,
-                          host_spans, op_name, union_length)
+import numpy as np
+
+from trace_reduce import NO_EVENTS, Trace, gaps, op_name, union_length
 
 PHASES = ("plcg_scalar", "plcg_vector", "plcg_control", "plcg_restart",
           "plcg_init")
 VECTOR = "plcg_vector"
-SUBPHASES = ("spmv", "recur", "fill", "dots", "xp")
+SUBPHASES = ("spmv", "recur", "fill", "dots", "xp", "prec")
 UNSCOPED = "unscoped"
 HALO = "halo_xchg"
-MODULES_LINE = "XLA Modules"
 TOP = 10
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
@@ -106,108 +105,95 @@ class PhaseSummary:
                 "loop_gaps": [[k, v] for k, v in self.loop_gaps]}
 
 
-def module_spans(pd) -> dict[int, list[tuple[int, int]]]:
-    """chip index -> the (start_ns, end_ns) of its ``XLA Modules`` events."""
-    out = {}
-    for plane in pd.planes:
-        m = DEVICE_PLANE.match(plane.name)
-        if not m:
-            continue
-        for line in plane.lines:
-            if line.name == MODULES_LINE:
-                out[int(m.group(1))] = [(int(ev.start_ns), int(ev.end_ns))
-                                        for ev in line.events]
-    return out
+def _spans(events):
+    """Merged program spans: (starts, ends, the length before each)."""
+    if not len(events):
+        z = np.zeros(0, np.int64)
+        return z, z, z
+    reach = np.maximum.accumulate(events.end)
+    first = np.flatnonzero(np.concatenate(
+        ([True], events.start[1:] > reach[:-1])))
+    starts = events.start[first]
+    ends = reach[np.append(first[1:] - 1, len(reach) - 1)]
+    return starts, ends, np.concatenate(([0], np.cumsum(ends - starts)))
 
 
-def _merge(intervals):
-    out = []
-    for s, e in sorted(intervals):
-        if out and s <= out[-1][1]:
-            out[-1] = (out[-1][0], max(out[-1][1], e))
-        else:
-            out.append((s, e))
-    return out
+def _covered_before(x, starts, ends, before):
+    """How much of the merged spans lies before each time of ``x``."""
+    j = np.searchsorted(starts, x, "right") - 1
+    jj = np.maximum(j, 0)
+    part = np.clip(x - starts[jj], 0, ends[jj] - starts[jj])
+    return np.where(j >= 0, before[jj] + part, 0)
 
 
-def _inside(gap, spans, starts):
-    """The parts of ``gap`` that lie inside the merged ``spans``."""
-    s, e = gap
-    out = []
-    for ms, me in spans[max(bisect.bisect_right(starts, s) - 1, 0):]:
-        if ms >= e:
-            break
-        a, b = max(s, ms), min(e, me)
-        if b > a:
-            out.append((a, b))
-    return out
-
-
-def summarize(pd, chips: int, hlo_text: str) -> PhaseSummary:
+def summarize(trace: Trace, chips: int, hlo_text: str) -> PhaseSummary:
     """Phase times of the window's leaf ops, joined by instruction name to
     ``hlo_text``, the compiled solver program's text."""
     paths = op_paths(hlo_text)
-    ops = device_ops(pd)
-    used = sorted(ops)[:chips]
-    if not used:
-        raise ValueError("the trace holds no device op line")
-    windows = [(s, e) for name, s, e in host_spans(pd) if name == WINDOW_SPAN]
-    if windows:
-        lo, hi = windows[0]
-    else:
-        lo = min(s for k in used for _, s, _ in ops[k][0])
-        hi = max(e for k in used for _, _, e in ops[k][0])
-    modules = module_spans(pd)
-    kinds: dict[str, tuple[str, str | None, bool]] = {}
+    used = trace.used(chips)
+    lo, hi = trace.window(used)
+    kinds: dict[str, tuple[str, tuple[str, str | None, bool]]] = {}
 
     def kind(text):
-        name = op_name(text)
-        if name not in kinds:
-            kinds[name] = classify(paths.get(name))
-        return name, kinds[name]
+        if text not in kinds:
+            name = op_name(text)
+            kinds[text] = name, classify(paths.get(name))
+        return kinds[text]
 
-    def clip(events):
-        return sorted(((n, max(s, lo), min(e, hi)) for n, s, e in events
-                       if e > lo and s < hi), key=lambda ev: ev[1:])
-
-    busy = halo = loop_idle = host_idle = 0.0
+    busy = halo = loop_idle = host_idle = 0
+    leaf_t: dict[str, int] = {}        # device time per instruction text
+    loop_gaps: dict[str, float] = {}
+    for k in used:
+        leaf = trace.ops[k].clip(lo, hi)
+        asyn = trace.async_ops.get(k, NO_EVENTS).clip(lo, hi)
+        busy += union_length(leaf.start, leaf.end)
+        for n, t in leaf.time_by_text().items():
+            leaf_t[n] = leaf_t.get(n, 0) + t
+        exchange = {n for n in leaf.texts + asyn.texts if kind(n)[1][2]}
+        if exchange:
+            h = (leaf.select(exchange), asyn.select(exchange))
+            halo += union_length(np.concatenate([x.start for x in h]),
+                                 np.concatenate([x.end for x in h]))
+        a, b, nxt = gaps(leaf.start, leaf.end, lo, hi)
+        starts, ends, before = _spans(trace.modules.get(k, NO_EVENTS)
+                                      .clip(lo, hi))
+        if not len(starts):
+            host_idle += int((b - a).sum())
+            continue
+        inside = (_covered_before(b, starts, ends, before)
+                  - _covered_before(a, starts, ends, before))
+        loop_idle += int(inside.sum())
+        host_idle += int((b - a).sum() - inside.sum())
+        if k != used[0]:
+            continue
+        # The part of a gap inside a program that ends where the gap
+        # ends is the wait for the op the gap ends at; the rest of its
+        # time inside programs ends at a program's end.
+        j = np.searchsorted(starts, b, "left") - 1
+        jj = np.maximum(j, 0)
+        at_op = (j >= 0) & (ends[jj] >= b) & (nxt >= 0)
+        wait = np.where(at_op, b - np.maximum(a, starts[jj]), 0)
+        by_text = np.bincount(leaf.ids[nxt[at_op]], weights=wait[at_op],
+                              minlength=len(leaf.texts))
+        for text_id in np.flatnonzero(by_text):
+            name, (phase, _, _) = kind(leaf.texts[text_id])
+            label = f"{phase} {name}"
+            loop_gaps[label] = (loop_gaps.get(label, 0.0)
+                              + float(by_text[text_id]))
+        end = float(inside.sum() - wait.sum())
+        if end > 0:
+            loop_gaps["program end"] = loop_gaps.get("program end", 0.0) + end
     phase_s: dict[str, float] = {}
     sub_s: dict[str, float] = {}
     unscoped: dict[str, float] = {}
-    loop_gaps: dict[str, float] = {}
-    for k in used:
-        leaf, asyn = clip(ops[k][0]), clip(ops[k][1])
-        iv = [(s, e) for _, s, e in leaf]
-        busy += union_length(iv)
-        for n, s, e in leaf:
-            name, (phase, sub, _) = kind(n)
-            phase_s[phase] = phase_s.get(phase, 0.0) + (e - s)
-            if sub is not None:
-                key = f"{VECTOR}/{sub}"
-                sub_s[key] = sub_s.get(key, 0.0) + (e - s)
-            if phase == UNSCOPED:
-                unscoped[name] = unscoped.get(name, 0.0) + (e - s)
-        halo += union_length([(s, e) for n, s, e in leaf + asyn
-                              if kind(n)[1][2]])
-        spans = _merge([(max(s, lo), min(e, hi))
-                        for s, e in modules.get(k, []) if e > lo and s < hi])
-        starts = [s for s, _ in spans]
-        leaf_starts = [s for _, s, _ in leaf]
-        for gap in gaps(iv, lo, hi):
-            inside = _inside(gap, spans, starts)
-            length = sum(b - a for a, b in inside)
-            loop_idle += length
-            host_idle += (gap[1] - gap[0]) - length
-            if k != used[0]:
-                continue
-            for a, b in inside:
-                j = bisect.bisect_left(leaf_starts, b)
-                if j < len(leaf) and leaf[j][1] == b:
-                    name, (phase, _, _) = kind(leaf[j][0])
-                    label = f"{phase} {name}"
-                else:
-                    label = "program end"
-                loop_gaps[label] = loop_gaps.get(label, 0.0) + (b - a)
+    for n, t in leaf_t.items():
+        name, (phase, sub, _) = kind(n)
+        phase_s[phase] = phase_s.get(phase, 0.0) + t
+        if sub is not None:
+            key = f"{VECTOR}/{sub}"
+            sub_s[key] = sub_s.get(key, 0.0) + t
+        if phase == UNSCOPED:
+            unscoped[name] = unscoped.get(name, 0.0) + t
     scale = 1e-9 / len(used)
     return PhaseSummary(
         window_s=(hi - lo) * 1e-9, busy_s=busy * scale,
@@ -220,27 +206,3 @@ def summarize(pd, chips: int, hlo_text: str) -> PhaseSummary:
         unscoped_ops=[(k, v * scale) for k, v in sorted(
             unscoped.items(), key=lambda kv: -kv[1])[:TOP]],
         chips=len(used))
-
-
-def readings(ps: PhaseSummary, updates: int, solves: int) -> dict[str, float]:
-    """The per-layer readings of one traced window: per update or per
-    solve of the window.  A phase reading is left out where the program
-    carries no phase scopes, the halo reading where no op is a halo
-    exchange."""
-    out = {}
-    if updates and ps.scoped:
-        out["vector_phase_ms_per_update"] = \
-            1e3 * ps.phase_s.get("plcg_vector", 0.0) / updates
-        out["scalar_phase_us_per_update"] = \
-            1e6 * ps.phase_s.get("plcg_scalar", 0.0) / updates
-    if solves and ps.scoped:
-        out["cycle_setup_ms_per_solve"] = 1e3 * (
-            ps.phase_s.get("plcg_init", 0.0)
-            + ps.phase_s.get("plcg_restart", 0.0)) / solves
-    if updates and ps.halo_s > 0:
-        out["halo_us_per_update"] = 1e6 * ps.halo_s / updates
-    if updates:
-        out["loop_idle_us_per_update"] = 1e6 * ps.loop_idle_s / updates
-    if solves:
-        out["host_idle_ms_per_solve"] = 1e3 * ps.host_idle_s / solves
-    return out

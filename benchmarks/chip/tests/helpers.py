@@ -38,13 +38,14 @@ def make_checkout(tmp: Path, configs: dict[str, dict]) -> Path:
 
 
 def run(root: Path, workload: str, capsys, seed: int = 2**31 + 3,
-        seconds: float = 0.5) -> tuple[int, dict | None]:
+        seconds: float = 0.5, trace: bool = False
+        ) -> tuple[int, dict | None]:
     """One CPU run of ``workload`` in ``root``: (exit code, the result the
     last stdout line carries, or None)."""
     import run as entry
 
     rc = entry.main(["--workload", workload, "--seed", str(seed),
-                     "--seconds", str(seconds), "--trace", "0"],
+                     "--seconds", str(seconds), "--trace", str(int(trace))],
                     root=root, bench_dir=root / "benchmarks" / "chip",
                     require_chip=False)
     out = capsys.readouterr().out.strip().splitlines()

@@ -3,6 +3,9 @@
 float32) and each fault the cells can have, planted under the timed path,
 come out not correct."""
 
+import json
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -33,6 +36,41 @@ def test_sound_run_is_correct(checkout, capsys, config):
     assert rc == 0 and result["correct"] is True
     assert result["failed"] == 0
     assert _checks(result)["worst_true_rel"] <= 2e-4
+
+
+def _updates_per_solve(root, workload, capsys):
+    """(result, mean updates per solve from the ``[window]`` lines) of one
+    CPU run of ``workload``."""
+    import run as entry
+
+    rc = entry.main(["--workload", workload, "--seed", str(2**31 + 17),
+                     "--seconds", "0.5", "--trace", "0"],
+                    root=root, bench_dir=root / "benchmarks" / "chip",
+                    require_chip=False)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0
+    iters = [int(m.group(1)) for ln in lines
+             if (m := re.match(r"\[window\] solve \d+: iters=(\d+)", ln))]
+    return json.loads(lines[-1]), sum(iters) / len(iters)
+
+
+@pytest.mark.parametrize("prec", [{"prec": "jacobi"},
+                                  {"prec": "blockjacobi", "block_size": 24}],
+                         ids=["jacobi", "blockjacobi"])
+@pytest.mark.parametrize("config", sorted(CELLS))
+def test_preconditioned_run_is_correct(checkout, capsys, config, prec):
+    """A configuration that names a preconditioner runs through the
+    harness and is correct; block-Jacobi over whole grid lines takes fewer
+    updates than no preconditioner."""
+    base = small_config(config)
+    result, updates = _updates_per_solve(
+        checkout(problem=dict(base["problem"], **prec)), CELLS[config],
+        capsys)
+    assert result["correct"] is True and result["failed"] == 0
+    assert _checks(result)["worst_true_rel"] <= 2e-4
+    if prec["prec"] == "blockjacobi":
+        _, plain = _updates_per_solve(checkout(), CELLS[config], capsys)
+        assert updates < plain
 
 
 @pytest.mark.parametrize("config", sorted(CELLS))

@@ -9,7 +9,9 @@ psum.  Both chips have an ``XLA Modules`` line.
 import pytest
 from jax.profiler import ProfileData
 
-from phase_reduce import classify, op_paths, readings, summarize
+from harness import RunRecord, Solve, load_module, metric_path
+from phase_reduce import classify, op_paths, summarize
+from trace_reduce import read
 from trace_reduce import summarize as trace_summarize
 
 NS = 1000  # picoseconds per nanosecond
@@ -114,7 +116,7 @@ CHIP1 = [_ev("fusion.7", 1000, 2000),
          _ev("fusion.15", 22000, 23000)]
 CHIP1_ASYNC = [_ev("collective-permute-start.1", 1800, 2300)]
 CHIP1_MODULES = [(30, 1000, 10000)]
-HOST = {1: "bench.window", 2: "bench.solve"}
+HOST = {1: "bench.traced", 2: "bench.solve"}
 HOST_EVENTS = [(1, 1000, 21000), (2, 1000, 10500), (2, 10500, 21000)]
 
 
@@ -140,11 +142,32 @@ def _trace(modules=True):
         return _plane(pid, f"/device:TPU:{pid - 1}", lines,
                       {**EVENTS, **MODULES})
 
-    return ProfileData.from_text_proto("\n".join([
+    return read(ProfileData.from_text_proto("\n".join([
         device(1, CHIP0, CHIP0_MODULES),
         device(2, CHIP1, CHIP1_MODULES, CHIP1_ASYNC),
         _plane(3, "/host:CPU", [_line(1, "main", HOST_EVENTS)], HOST),
-    ]))
+    ])))
+
+
+PHASE_METRICS = ("vector_phase_ms_per_update", "scalar_phase_us_per_update",
+                 "cycle_setup_ms_per_solve", "halo_us_per_update",
+                 "loop_idle_us_per_update", "host_idle_ms_per_solve")
+
+
+def readings(phases, updates, solves):
+    """The phase metrics' readers on a run of ``solves`` solves of
+    ``updates`` updates in all, with ``phases`` as its phase summary."""
+    each = [Solve(iters=updates // solves, restarts=1, converged=True,
+                  seconds=1.0) for _ in range(solves)]
+    rec = RunRecord(chips=phases.chips, n=1000, l=2, itemsize=4, tol=2e-4,
+                    peaks=None, setup_s=1.0, window_s=phases.window_s,
+                    solves=each, phases=phases)
+    out = {}
+    for name in PHASE_METRICS:
+        v = load_module(metric_path(name)).read(rec)
+        if v is not None:
+            out[name] = v
+    return out
 
 
 def test_classify_takes_the_innermost_phase():
@@ -160,6 +183,17 @@ def test_classify_takes_the_innermost_phase():
     assert "copy.202" not in paths
     assert classify(paths.get("copy.202")) == ("unscoped", None, False)
     assert classify(paths["while.3"]) == ("unscoped", None, False)
+
+
+def test_classify_reads_a_prec_sub_scope():
+    """A preconditioner's solve under its own ``prec`` scope in the vector
+    phase is a sub-phase of its own, with no edit to the reduction."""
+    path = (ITER + "plcg_vector/prec/dot_general").split("/")
+    assert classify(path) == ("plcg_vector", "prec", False)
+    hlo = _hlo({"fusion.40": ("f32[4000000]{0} fusion(f32[4000000]{0} %r),"
+                              " kind=kOutput", "/".join(path))})
+    assert classify(op_paths(hlo)["fusion.40"]) == \
+        ("plcg_vector", "prec", False)
 
 
 def test_phase_summary_known_answer():

@@ -3,7 +3,8 @@
 import pytest
 from jax.profiler import ProfileData
 
-from trace_reduce import gaps, leaves, op_name, summarize, union_length
+from trace_reduce import (gaps, leaves, op_name, read, summarize,
+                          union_length)
 
 NS = 1000  # picoseconds per nanosecond
 
@@ -38,7 +39,7 @@ CHIP0 = [(4, 1000, 9000), (5, 1000, 5000), (1, 1000, 3000), (2, 4000, 5000),
          (1, 6000, 9000)]
 CHIP1 = [(1, 1000, 5000), (1, 12000, 13000)]
 CHIP1_ASYNC = [(3, 4500, 6000)]
-HOST = {1: "bench.window", 2: "bench.solve", 3: "host.sync"}
+HOST = {1: "bench.traced", 2: "bench.solve", 3: "host.sync"}
 HOST_EVENTS = [(1, 1000, 11000), (2, 1000, 8500), (2, 8500, 11000)]
 
 
@@ -58,19 +59,27 @@ def _trace(window=True):
 def test_op_name_and_leaves():
     assert op_name(OPS[4]) == "while.7"
     assert op_name("copy.1") == "copy.1"
-    ev = [("w", 0, 10), ("c", 0, 6), ("a", 0, 2), ("b", 3, 6), ("d", 7, 10)]
-    assert sorted(n for n, _, _ in leaves(ev)) == ["a", "b", "d"]
+    names = ["w", "c", "a", "b", "d"]
+    keep = leaves([0, 0, 0, 3, 7], [10, 6, 2, 6, 10])
+    assert sorted(n for n, k in zip(names, keep) if k) == ["a", "b", "d"]
+    assert leaves([], []).tolist() == []
 
 
 def test_union_and_gaps():
-    iv = [(5, 8), (0, 2), (1, 3), (7, 9)]
-    assert union_length(iv) == 3 + 4
-    assert gaps(iv, 0, 12) == [(3, 5), (9, 12)]
-    assert gaps(iv, 1, 2) == []
+    start, end = [0, 1, 5, 7], [2, 3, 8, 9]       # ordered by start
+    assert union_length(start, end) == 3 + 4
+    assert union_length(start[::-1], end[::-1]) == 3 + 4
+    assert union_length([0, 1, 2], [10, 3, 4]) == 10   # nested
+    assert union_length([], []) == 0
+    a, b, nxt = gaps(start, end, 0, 12)
+    assert list(zip(a, b, nxt)) == [(3, 5, 2), (9, 12, -1)]
+    assert [len(g) for g in gaps(start, end, 1, 2)] == [0, 0, 0]
+    a, b, nxt = gaps([], [], 4, 6)
+    assert list(zip(a, b, nxt)) == [(4, 6, -1)]
 
 
 def test_summary_known_answer():
-    s = summarize(_trace(), chips=2)
+    s = summarize(read(_trace()), chips=2)
     assert s.chips == 2
     assert s.window_s == pytest.approx(10000e-9)
     assert s.busy_s == pytest.approx((6000 + 4000) / 2 * 1e-9)
@@ -85,13 +94,13 @@ def test_summary_known_answer():
 
 
 def test_one_chip_of_two():
-    s = summarize(_trace(), chips=1)
+    s = summarize(read(_trace()), chips=1)
     assert s.busy_s == pytest.approx(6000e-9)
     assert s.allreduce_s == pytest.approx(1000e-9)
 
 
 def test_window_falls_back_to_device_span():
-    s = summarize(_trace(window=False), chips=2)
+    s = summarize(read(_trace(window=False)), chips=2)
     assert s.window_s == pytest.approx(12000e-9)   # 1000 .. 13000
 
 
@@ -99,4 +108,4 @@ def test_no_device_plane_raises():
     pd = ProfileData.from_text_proto(
         _plane(3, "/host:CPU", [_line(1, "main", HOST_EVENTS)], HOST))
     with pytest.raises(ValueError, match="no device op line"):
-        summarize(pd, chips=1)
+        read(pd)
